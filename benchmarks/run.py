@@ -33,6 +33,9 @@ def main() -> None:
                          "repo root, where the CI bench lane uploads them "
                          "from); 'none' disables JSON output")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     wanted = set(args.only.split(",")) if args.only else None
     json_dir = None if args.json_dir == "none" else args.json_dir
 

@@ -39,6 +39,7 @@ from repro.core.mechanisms import (
 )
 from repro.fed import FedConfig, FedTrainer
 from repro.fed.engine import engine_names
+from repro.launch.compile_cache import enable_compile_cache
 from repro.privacy.calibrate import DEFAULT_ALPHAS, calibrate, calibration_knobs
 from repro.telemetry import parse_tracker_spec
 
@@ -224,6 +225,7 @@ def main():
                          "suffixed file (like the checkpoint subdirs)")
     ap.add_argument("--out", default=None, help="write results JSON")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.resume and not args.ckpt_dir:
         ap.error("--resume requires --ckpt-dir")
 

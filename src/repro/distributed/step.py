@@ -35,19 +35,6 @@ from repro.models.common import ParallelCtx
 from repro.optim import Optimizer
 
 
-def compat_shard_map(body, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: the stable jax.shard_map
-    (check_vma) when present, else the 0.4.x experimental shard_map
-    (same semantics, check_rep spelling)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshPlan:
     """Binding of mesh axes to roles.
@@ -383,7 +370,7 @@ def make_train_step(cfg: ModelConfig, plan: MeshPlan, mech: Mechanism,
     opt_specs = meta_lib.pspecs(opt_meta) if opt_meta else ()
 
     metric_specs = {k: P() for k in ("loss", "ce_loss", "moe_aux_loss")}
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=plan.mesh,
         in_specs=(param_specs, opt_specs, P(), batch_specs, P()),
@@ -441,7 +428,7 @@ def make_decode_step(cfg: ModelConfig, plan: MeshPlan, shape: InputShape, *,
     tok_spec = P(None if seq_sharded else plan.client_axes, None)
     out_tok_spec = P(None if seq_sharded else plan.client_axes)
 
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=plan.mesh,
         in_specs=(param_specs, cache_specs, tok_spec, P()),
@@ -496,7 +483,7 @@ def make_prefill_step(cfg: ModelConfig, plan: MeshPlan, shape: InputShape, *,
 
         in_specs = (param_specs, tok_spec)
 
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=plan.mesh,
         in_specs=in_specs,

@@ -212,15 +212,12 @@ class AsyncEngine(Engine):
                 ids, _ = cohort.sample_slate(cfg, slate, k_sample)
                 batch = rounds.index_batch(data, ids)
             if S == 0:
-                grads = jax.vmap(client_grad, in_axes=(None, 0))(
-                    hist[0], batch
-                )
+                grads = rounds.cohort_grads(client_grad, hist[0], batch)
             else:
                 # each buffer member computed against the version it
                 # fetched: gather per-row parameters from the ring
-                grads = jax.vmap(client_grad, in_axes=(0, 0))(
-                    hist[stale], batch
-                )
+                grads = rounds.cohort_grads(client_grad, hist[stale], batch,
+                                            per_client_params=True)
             part = delivered
             if fused:
                 z_sum = mech.quantize_sum_batch(grads, k_enc, weights=part)

@@ -28,7 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import wire
 from repro.data.federated import sample_clients
-from repro.distributed.step import MeshPlan, compat_shard_map
+from repro.distributed.step import MeshPlan
 from repro.fed import cohort, rounds, staging
 from repro.fed.engine import Engine, register_engine
 from repro.launch.mesh import make_fed_mesh, make_shard_mesh
@@ -254,7 +254,7 @@ class ShardEngine(Engine):
                                 model_axis="model")
             assert tr._plan.tp == self.model_shards
             # no client axes on the task ctx: a client's loss must stay
-            # local to its shard (client_grad is vmapped over the cohort
+            # local to its shard (client_grad is mapped over the cohort
             # slice WITHIN a shard — cross-client collectives would sum
             # across cohort members)
             tr._task_ctx = ParallelCtx(model_axis="model",
@@ -288,11 +288,12 @@ class ShardEngine(Engine):
             # 2-D mesh both specs leave the model axis unmentioned: data
             # and carried state are replicated across model shards (the
             # tensor-parallel slicing happens INSIDE client_grad).
-            mapped = compat_shard_map(
+            mapped = jax.shard_map(
                 block_l,
                 mesh=tr._mesh,
                 in_specs=(P(), P(), P(), data_spec),
                 out_specs=(P(), P(), P(), P(), P()),
+                check_vma=False,
             )
             return jax.jit(mapped, donate_argnums=(0, 1))
 

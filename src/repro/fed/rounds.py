@@ -134,6 +134,21 @@ def make_client_grad(mech, unravel, cfg, task, ctx=None):
     return client_grad
 
 
+def cohort_grads(client_grad, params, batch, *, per_client_params=False):
+    """Every cohort member's ``client_grad``, one client at a time
+    (``lax.map``): a client's gradient is then one fixed program whatever
+    the cohort size or its split over chips. A vmap folds the clients
+    into the batch of the convolutions and matmuls, and on a TPU the
+    float rounding of each client's gradient then depends on how many
+    clients share the call — enough to change the released integers
+    between the shard engine's per-chip slice and the whole cohort.
+    ``per_client_params``: ``params`` carries a leading client axis (the
+    async engine's per-member parameter versions)."""
+    if per_client_params:
+        return jax.lax.map(lambda pb: client_grad(*pb), (params, batch))
+    return jax.lax.map(lambda b: client_grad(params, b), batch)
+
+
 def make_server_apply(opt, cfg, hetero):
     """The decode-then-apply boundary: g_hat -> (new_params, new_state)
     via the pluggable server optimizer. Empty heterogeneous rounds (zero
@@ -168,9 +183,7 @@ def make_round_step(mech, cfg, opt, slate, client_grad):
     def round_step(flat, opt_state, key, data):
         key, k_sample, k_enc, k_drop = cohort.split_round_keys(cfg, key)
         ids, valid = cohort.sample_slate(cfg, slate, k_sample)
-        grads = jax.vmap(client_grad, in_axes=(None, 0))(
-            flat, index_batch(data, ids)
-        )
+        grads = cohort_grads(client_grad, flat, index_batch(data, ids))
         # Shared clip->encode dispatch (clip is idempotent on the
         # already-clipped grads): one fused kernel call over the whole
         # (clients, dim) stack when the mechanism is kernel-backed. With
@@ -299,7 +312,7 @@ def make_shard_round_step(mech, cfg, opt, slate, shards, client_grad):
             if multi:
                 ids = jax.lax.dynamic_slice_in_dim(ids, j * n_per, n_per)
             batch = index_batch(data, ids)
-        grads = jax.vmap(client_grad, in_axes=(None, 0))(flat, batch)
+        grads = cohort_grads(client_grad, flat, batch)
         local = None
         if hetero:
             # replicated full-slate participation; each shard masks its

@@ -306,11 +306,9 @@ class LmTask(ClientTask):
             if self.tp > 1:
                 from jax.sharding import PartitionSpec as P
 
-                from repro.distributed.step import compat_shard_map
-
-                ce = compat_shard_map(
+                ce = jax.shard_map(
                     ce, mesh=self._eval_mesh, in_specs=(P(), P()),
-                    out_specs=(P(), P()),
+                    out_specs=(P(), P()), check_vma=False,
                 )
             self._eval_jit = jax.jit(ce)
         tot_ce = tot_tok = 0.0

@@ -12,6 +12,7 @@ execute lives in the registered engines (``repro.fed.engines``).
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Optional
 
@@ -219,9 +220,8 @@ class FedTrainer:
             mech, unravel, self.cfg, self.task, ctx=ctx
         )
         if ctx is None:
-            self._client_grads = jax.jit(
-                jax.vmap(self._client_grad, in_axes=(None, 0))
-            )
+            self._client_grads = jax.jit(functools.partial(
+                rounds.cohort_grads, self._client_grad))
         self._encode = jax.jit(jax.vmap(mech.encode, in_axes=(0, 0)))
         self._quantize_batch = jax.jit(lambda g, k: mech.quantize_batch(g, k))
         self._decode = jax.jit(lambda zsum, n: mech.decode_sum(zsum, n))

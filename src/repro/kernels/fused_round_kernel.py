@@ -108,7 +108,10 @@ def round_sum_2d(x, w, seed, row_offset, encode, params, *, dim: int,
        across the lane so the tile multiply is a plain vreg op).
     seed, row_offset: (1, 1) uint32 scalars.
     dim: the TRUE feature width the RNG counters index (<= dim_p).
-    Returns (dim_p // 128, 128) int32 column sums (reshape(-1)[:dim]).
+    Returns (dim_p // 128, 1, 128) int32 column sums (reshape(-1)[:dim]).
+    The output is 3-D so each (1, 128) block spans the array's last two
+    dimensions — the TPU block rule, which a (1, 128) block of a
+    (dim_p // 128, 128) array breaks.
     """
     rows_p, dim_p = x.shape
     if dim_p % LANE:
@@ -128,8 +131,8 @@ def round_sum_2d(x, w, seed, row_offset, encode, params, *, dim: int,
             pl.BlockSpec((block_rows, LANE), lambda c, r: (r, c)),
             pl.BlockSpec((block_rows, LANE), lambda c, r: (r, 0)),  # weights
         ],
-        out_specs=pl.BlockSpec((1, LANE), lambda c, r: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((dim_p // LANE, LANE), jnp.int32),
+        out_specs=pl.BlockSpec((None, 1, LANE), lambda c, r: (c, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((dim_p // LANE, 1, LANE), jnp.int32),
         interpret=interpret,
     )(seed.reshape(1, 1), row_offset.reshape(1, 1), x, w)
 
@@ -231,8 +234,9 @@ def round_sum_packed_2d(x, w, seed, row_offset, encode, params, *,
 
     x: (rows_p, fields*words) float, words % 128 == 0 (the lane-aligned
     word-count case; unaligned sizes pack the dense kernel's output
-    instead — see ``round_sum``). Returns (words // 128, 128) int32
-    packed words (``reshape(-1)`` for the (words,) wire vector).
+    instead — see ``round_sum``). Returns (words // 128, 1, 128) int32
+    packed words (``reshape(-1)`` for the (words,) wire vector), 3-D for
+    the same block rule as ``round_sum_2d``.
     """
     rows_p, dim_p = x.shape
     fields = wire.fields_per_word(bits)
@@ -259,8 +263,8 @@ def round_sum_packed_2d(x, w, seed, row_offset, encode, params, *,
                          lambda i, f, r, wb=wb: (r, f * wb + i)),
             pl.BlockSpec((block_rows, LANE), lambda i, f, r: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((1, LANE), lambda i, f, r: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((wb, LANE), jnp.int32),
+        out_specs=pl.BlockSpec((None, 1, LANE), lambda i, f, r: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((wb, 1, LANE), jnp.int32),
         interpret=interpret,
     )(seed.reshape(1, 1), row_offset.reshape(1, 1), x, w)
 
@@ -307,6 +311,13 @@ def round_sum_packed_jnp(x, w, seed, row_offset, encode_name: str, params,
     return acc
 
 
+def packed_kernel_engages(dim: int, bits: int) -> bool:
+    """True when the packed grid tiles ``dim``'s canonical wire words (a
+    lane-aligned word count). Otherwise ``round_sum`` runs the dense
+    kernel and packs its output — bit-identical, one extra pass."""
+    return wire.packed_words(dim, bits) % LANE == 0
+
+
 def round_sum(x, key_seed, params, encode_name: str, *, weights=None,
               row_offset=None, block_rows=None, interpret=None,
               compute_dtype=jnp.float32, pack_bits=None):
@@ -345,9 +356,9 @@ def round_sum(x, key_seed, params, encode_name: str, *, weights=None,
         (rows_p, LANE),
     )
     if pack_bits is not None:
-        fields = wire.fields_per_word(pack_bits)
-        words = wire.packed_words(dim, pack_bits)
-        if words % LANE == 0:
+        if packed_kernel_engages(dim, pack_bits):
+            fields = wire.fields_per_word(pack_bits)
+            words = wire.packed_words(dim, pack_bits)
             # columns pad to fields*words so field f of word w is column
             # f*words + w; padded coordinates are zeroed in-kernel
             x2 = jnp.pad(x, ((0, rows_p - rows), (0, fields * words - dim)))

@@ -8,8 +8,9 @@ Every mechanism kernel gets the same three entry points, built once by
 ``_make_fast_ops`` from its (pallas ``*_quantize_2d``, element-wise
 ``*_block``) pair:
 
-  * ``<name>(x, key, params, *, block_rows, interpret)`` — the Pallas path
-    on an arbitrary-shape array (auto block sizing via pick_block_rows);
+  * ``<name>(x, key, params, *, offset, block_rows, interpret)`` — the
+    Pallas path on an arbitrary-shape array (auto block sizing via
+    pick_block_rows);
   * ``<name>_fast(x, key, params)`` — Pallas on TPU, the kernel's exact
     math as ONE fused jnp expression elsewhere. Bit-identical for the same
     seed (the counter-based RNG depends only on the flat element index);
@@ -37,9 +38,9 @@ but must draw the SAME randomness those rows would draw in the full (n, dim)
 batch. ``row_offset`` (a traced scalar — it is ``axis_index * n_per`` inside
 shard_map) shifts the counter-based RNG by ``row_offset * dim`` elements, so
 shard-local encodes are bit-identical to the corresponding rows of the
-unsharded batch encode. Offset encodes always take the fused-jnp path (the
-Pallas grid derives its counters from the program id alone); on this
-container that is the production path anyway.
+unsharded batch encode. The offset reaches the Pallas kernels as an
+operand, so on a TPU every encode — sharded or not — runs the same
+compiled kernel math.
 """
 from __future__ import annotations
 
@@ -59,10 +60,12 @@ def _interpret_default() -> bool:
 
 
 def _force_interpret() -> bool:
-    """CI hook: REPRO_PALLAS_INTERPRET=1 routes the fused round-sum path
-    through the Pallas kernel in interpret mode, so the kernel BODY (not
-    just the fused-jnp twin) is exercised on CPU-only runners."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "") not in ("", "0")
+    """CPU-test hook: REPRO_PALLAS_INTERPRET=1 routes the fused round-sum
+    path through the Pallas kernel in interpret mode, so the kernel BODY
+    (not just the fused-jnp twin) is exercised on CPU-only runners. On a
+    TPU the hook is ignored: kernels there always compile."""
+    return (jax.default_backend() != "tpu"
+            and os.environ.get("REPRO_PALLAS_INTERPRET", "") not in ("", "0"))
 
 
 def key_to_seed(key: jax.Array) -> jnp.ndarray:
@@ -97,21 +100,26 @@ def _make_fast_ops(quantize_2d, block_fn, name: str):
     """
 
     @functools.partial(jax.jit, static_argnames=("params", "block_rows", "interpret"))
-    def _flat(x_flat, seed, params, block_rows: int | None, interpret: bool):
+    def _flat(x_flat, seed, offset, params, block_rows: int | None,
+              interpret: bool):
         x2, n, block_rows = tile_flat(x_flat, block_rows)
-        z2 = quantize_2d(x2, seed, params, block_rows=block_rows,
-                         interpret=interpret)
+        z2 = quantize_2d(x2, seed, params, offset=offset,
+                         block_rows=block_rows, interpret=interpret)
         return z2.reshape(-1)[:n]
 
-    def pallas(x, key, params, *, block_rows=None, interpret=None):
+    def pallas(x, key, params, *, offset=None, block_rows=None,
+               interpret=None):
         """Quantize an arbitrary-shape array via the Pallas kernel.
 
-        block_rows=None auto-sizes the block to the input (tile_flat's
-        pick_block_rows clamp); an explicit value is honored as given."""
+        offset: optional (traced) element offset into the RNG stream (see
+        ``fast``). block_rows=None auto-sizes the block to the input
+        (tile_flat's pick_block_rows clamp); an explicit value is honored
+        as given."""
         if interpret is None:
             interpret = _interpret_default()
         seed = key_to_seed(key)
-        z = _flat(x.reshape(-1), seed, params, block_rows, interpret)
+        offset = jnp.asarray(0 if offset is None else offset).astype(jnp.uint32)
+        z = _flat(x.reshape(-1), seed, offset, params, block_rows, interpret)
         return z.reshape(x.shape)
 
     @functools.partial(jax.jit, static_argnames=("params",))
@@ -124,14 +132,11 @@ def _make_fast_ops(quantize_2d, block_fn, name: str):
 
         offset: optional (traced) element offset into the counter-based RNG
         stream — element i of ``x`` draws the randomness element ``offset+i``
-        of a larger flat input would draw. Offset encodes always use the
-        fused path (see module docstring)."""
-        if offset is None:
-            if jax.default_backend() == "tpu":
-                return pallas(x, key, params)
-            offset = jnp.uint32(0)
+        of a larger flat input would draw."""
+        if jax.default_backend() == "tpu":
+            return pallas(x, key, params, offset=offset)
         seed = key_to_seed(key)
-        offset = jnp.asarray(offset).astype(jnp.uint32)
+        offset = jnp.asarray(0 if offset is None else offset).astype(jnp.uint32)
         return _flat_jnp(x.reshape(-1), seed, offset, params).reshape(x.shape)
 
     def batch(x, key, params, *, row_offset=None):

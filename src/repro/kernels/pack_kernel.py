@@ -74,13 +74,13 @@ def pack_flat(z, bits: int, *, interpret: bool = False):
         return wire.pack_bits(z, bits)
     k, w = kw
     wb = w // LANE
-    z2 = jnp.pad(z.astype(jnp.int32), (0, k * w - n)).reshape(-1, LANE)
+    z2 = jnp.pad(z.astype(jnp.int32), (0, k * w - n)).reshape(-1, 1, LANE)
     out = pl.pallas_call(
         functools.partial(_pack_kernel, bits=bits),
         grid=(wb, k),  # field axis INNERMOST: word block i revisits over f
-        in_specs=[pl.BlockSpec((1, LANE), lambda i, f: (f * wb + i, 0))],
-        out_specs=pl.BlockSpec((1, LANE), lambda i, f: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((wb, LANE), jnp.int32),
+        in_specs=[pl.BlockSpec((None, 1, LANE), lambda i, f: (f * wb + i, 0, 0))],
+        out_specs=pl.BlockSpec((None, 1, LANE), lambda i, f: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((wb, 1, LANE), jnp.int32),
         interpret=interpret,
     )(z2)
     return out.reshape(-1)
@@ -107,13 +107,13 @@ def unpack_flat(words, bits: int, n: int, *, interpret: bool = False):
     if w % LANE or w != wire.packed_words(n, bits):
         return wire.unpack_bits(words, bits, n)
     wb = w // LANE
-    w2 = words.astype(jnp.int32).reshape(wb, LANE)
+    w2 = words.astype(jnp.int32).reshape(wb, 1, LANE)
     out = pl.pallas_call(
         functools.partial(_unpack_kernel, bits=bits),
         grid=(k, wb),
-        in_specs=[pl.BlockSpec((1, LANE), lambda f, i: (i, 0))],
-        out_specs=pl.BlockSpec((1, LANE), lambda f, i: (f * wb + i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k * wb, LANE), jnp.int32),
+        in_specs=[pl.BlockSpec((None, 1, LANE), lambda f, i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((None, 1, LANE), lambda f, i: (f * wb + i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k * wb, 1, LANE), jnp.int32),
         interpret=interpret,
     )(w2)
     return out.reshape(-1)[:n]
@@ -158,18 +158,23 @@ def unpack_decode_apply(w_flat, words, params, n: int, lr: float, *,
         return None
     scale = 2.0 * params.x_max / (n * (params.m - 1))
     wb = rows_w // block_rows
-    w2 = jnp.pad(w_flat, (0, k * w_cnt - dim)).reshape(-1, LANE)
-    z2 = words.astype(jnp.int32).reshape(rows_w, LANE)
+    # 3-D (blocks, block_rows, 128) views: every block spans the array's
+    # last two dimensions, which the TPU block rule accepts at any
+    # block_rows (a (1, 128) block of a 2-D array it refuses)
+    w3 = jnp.pad(w_flat, (0, k * w_cnt - dim)).reshape(-1, block_rows, LANE)
+    z3 = words.astype(jnp.int32).reshape(wb, block_rows, LANE)
+    block = (None, block_rows, LANE)
     out = pl.pallas_call(
         functools.partial(_unpack_decode_apply_kernel, x_max=params.x_max,
                           scale=scale, lr=lr, bits=pack_bits),
         grid=(k, wb),
         in_specs=[
-            pl.BlockSpec((block_rows, LANE), lambda f, i: (f * wb + i, 0)),
-            pl.BlockSpec((block_rows, LANE), lambda f, i: (i, 0)),
+            pl.BlockSpec(block, lambda f, i: (f * wb + i, 0, 0)),
+            pl.BlockSpec(block, lambda f, i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_rows, LANE), lambda f, i: (f * wb + i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k * rows_w, LANE), w_flat.dtype),
+        out_specs=pl.BlockSpec(block, lambda f, i: (f * wb + i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k * wb, block_rows, LANE),
+                                       w_flat.dtype),
         interpret=interpret,
-    )(w2, z2)
+    )(w3, z3)
     return out.reshape(-1)[:dim]

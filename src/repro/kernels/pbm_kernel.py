@@ -43,10 +43,11 @@ def _pbm_block(x, seed, base_offset, params: PBMParams):
     return pbm_encode_counters(x, seed, counter, params)
 
 
-def _kernel(seed_ref, x_ref, z_ref, *, params: PBMParams, block_rows: int):
+def _kernel(seed_ref, off_ref, x_ref, z_ref, *, params: PBMParams,
+            block_rows: int):
     pid = pl.program_id(0)
     seed = seed_ref[0, 0]
-    base = (pid * jnp.uint32(block_rows * LANE)).astype(jnp.uint32)
+    base = off_ref[0, 0] + (pid * jnp.uint32(block_rows * LANE)).astype(jnp.uint32)
     z_ref[...] = _pbm_block(x_ref[...], seed, base, params)
 
 
@@ -55,6 +56,7 @@ def pbm_quantize_2d(
     seed: jnp.ndarray,
     params: PBMParams,
     *,
+    offset,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -69,9 +71,10 @@ def pbm_quantize_2d(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),  # element offset
             pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
         interpret=interpret,
-    )(seed.reshape(1, 1), x)
+    )(seed.reshape(1, 1), offset.reshape(1, 1), x)

@@ -39,8 +39,13 @@ def random_bits(seed: jnp.ndarray, counter: jnp.ndarray, stream: int) -> jnp.nda
 
 
 def uniform01(bits: jnp.ndarray) -> jnp.ndarray:
-    """Map uint32 bits to float32 uniforms in [0, 1) using the top 24 bits."""
-    return (bits >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    """Map uint32 bits to float32 uniforms in [0, 1) using the top 24 bits.
+
+    The cast goes through int32: Mosaic has no uint32 -> float32 convert,
+    and ``bits >> 8 < 2**24`` is exact as a signed value and as a float.
+    """
+    top24 = (bits >> 8).astype(jnp.int32)
+    return top24.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
 
 
 def random_uniform(seed, counter, stream: int) -> jnp.ndarray:

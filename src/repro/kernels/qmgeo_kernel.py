@@ -49,10 +49,11 @@ def _qmgeo_block(x, seed, base_offset, params: QMGeoParams):
     return qmgeo_encode_counters(x, seed, counter, params)
 
 
-def _kernel(seed_ref, x_ref, z_ref, *, params: QMGeoParams, block_rows: int):
+def _kernel(seed_ref, off_ref, x_ref, z_ref, *, params: QMGeoParams,
+            block_rows: int):
     pid = pl.program_id(0)
     seed = seed_ref[0, 0]
-    base = (pid * jnp.uint32(block_rows * LANE)).astype(jnp.uint32)
+    base = off_ref[0, 0] + (pid * jnp.uint32(block_rows * LANE)).astype(jnp.uint32)
     z_ref[...] = _qmgeo_block(x_ref[...], seed, base, params)
 
 
@@ -61,13 +62,16 @@ def qmgeo_quantize_2d(
     seed: jnp.ndarray,
     params: QMGeoParams,
     *,
+    offset,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """pallas_call entry point on a pre-tiled (rows, 128) float array.
 
     rows must be a multiple of block_rows; use ops.qmgeo for arbitrary
-    shapes. seed: uint32 scalar array of shape (1, 1).
+    shapes. seed: uint32 scalar array of shape (1, 1). offset:
+    uint32 scalar (may be traced) — element (r, c) draws the counter of
+    flat element ``offset + r*128 + c``.
     """
     rows, cols = x.shape
     if cols != LANE:
@@ -80,9 +84,10 @@ def qmgeo_quantize_2d(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),  # seed: broadcast scalar
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),  # element offset
             pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
         interpret=interpret,
-    )(seed.reshape(1, 1), x)
+    )(seed.reshape(1, 1), offset.reshape(1, 1), x)

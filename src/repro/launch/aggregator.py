@@ -66,6 +66,7 @@ from repro.core.renyi import RenyiAccountant
 from repro.fed.engine import make_engine
 from repro.fed.updates import (ClientUpdate, StalenessPolicy, UpdateBuffer,
                                as_updates)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import make_optimizer
 from repro.telemetry import RoundEmitter, Timings, make_tracker
 
@@ -612,6 +613,7 @@ def main():
                          "mid-stream; asserts drain/backpressure/halt "
                          "invariants and exits nonzero on violation")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.dim, args.cohort, args.batch = 64, 4, 4
         args.batches, args.queue_limit = 10, 4

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # fake host devices, never the chip
 
 """Multi-pod dry-run: prove every (architecture x input-shape x mesh)
 combination lowers, compiles and fits, and extract the roofline terms.
@@ -40,7 +41,6 @@ from repro.launch import hlo_analysis
 from repro.launch.mesh import (
     V5E,
     client_axes_of,
-    compat_set_mesh,
     make_production_mesh,
 )
 from repro.models import meta as meta_lib
@@ -143,7 +143,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, mechanism="rqm",
     n_dev = mesh.devices.size
     t0 = time.time()
     try:
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, args = build_step(
                 cfg, plan, shape, mechanism=mechanism, packed=packed,
                 q_chunk=q_chunk, remat=remat, seq_parallel=seq_parallel,

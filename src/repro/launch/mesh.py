@@ -6,9 +6,8 @@ importing jax; smoke tests and benches see the real single CPU device.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AxisType
 
 V5E = {
     "peak_flops_bf16": 197e12,  # per chip
@@ -18,36 +17,17 @@ V5E = {
 }
 
 
-def compat_set_mesh(mesh):
-    """Context manager entering ``mesh``: jax.set_mesh on newer jax, the
-    mesh's own (legacy) context-manager protocol on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: newer jax wants explicit Auto
-    axis_types (the repo's shard_map code assumes Auto), older jax (e.g.
-    0.4.x) has neither the kwarg nor jax.sharding.AxisType — where Auto is
-    already the only behavior."""
-    if "axis_types" in inspect.signature(jax.make_mesh).parameters:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the repo's shard_map code assumes
+    Auto, and jax.make_mesh defaults to Explicit."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
-
-
-def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CI-scale sharded tests (requires >=prod(shape) devices)."""
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_shard_mesh(shards: int = None):
@@ -63,7 +43,7 @@ def make_shard_mesh(shards: int = None):
             f"(on CPU export XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{shards} before importing jax)"
         )
-    return compat_make_mesh((shards,), ("shard",))
+    return make_mesh((shards,), ("shard",))
 
 
 def make_fed_mesh(shards: int, model_shards: int):
@@ -80,7 +60,7 @@ def make_fed_mesh(shards: int, model_shards: int):
             f"--xla_force_host_platform_device_count={want} before "
             f"importing jax)"
         )
-    return compat_make_mesh((shards, model_shards), ("shard", "model"))
+    return make_mesh((shards, model_shards), ("shard", "model"))
 
 
 def client_axes_of(mesh) -> tuple[str, ...]:

@@ -40,7 +40,8 @@ from repro.distributed.step import (
     make_train_step,
     round_privacy,
 )
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import meta as meta_lib
 from repro.models import model as model_lib
 from repro.models.common import ParallelCtx
@@ -121,6 +122,7 @@ def main():
                          "('shard', 'model') mesh (needs fed-shards * "
                          "model-shards devices)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.fed_lm:
         return _fed_lm(args, ap)
 
@@ -134,7 +136,7 @@ def main():
             # the param pspecs (which name 'model') valid on this mesh
             dims = (dims[0], 1)
         names = ("pod", "data", "model")[-len(dims):]
-        mesh = compat_make_mesh(dims, names)
+        mesh = make_mesh(dims, names)
         plan = MeshPlan(
             mesh=mesh,
             client_axes=tuple(a for a in names if a != "model"),
@@ -196,7 +198,7 @@ def main():
             compute_dtype=jnp.float32,
         )
         tp = plan.tp
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = model_lib.init_params(jax.random.key(args.seed + 1), cfg, tp=tp)
             params = jax.device_put(params, meta_lib.shardings(specs["param_meta"], mesh))
             opt_state = opt.init(params)

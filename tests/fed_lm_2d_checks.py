@@ -25,6 +25,7 @@ Checks (the ISSUE-9 tentpole acceptance contract):
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"  # fake host devices, never the chip
 
 import jax
 import numpy as np

@@ -14,6 +14,7 @@ Checks:
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"  # fake host devices, never the chip
 
 import dataclasses as dc
 
@@ -24,12 +25,11 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import InputShape
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_config
 from repro.core.mechanisms import make_mechanism
 from repro.distributed.step import (
     MeshPlan,
-    compat_shard_map,
     make_decode_step,
     make_train_step,
 )
@@ -76,7 +76,7 @@ def relayout_tp(params1, cfg, tp):
 
 
 def check_tp_equivalence():
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     TP = 4
     for arch in ("gemma3-4b", "qwen3-moe-30b-a3b", "mamba2-370m",
                  "zamba2-1.2b", "musicgen-medium"):
@@ -126,9 +126,9 @@ def check_tp_equivalence():
         pspecs = meta_lib.pspecs(metaN)
         bspecs = {k: P("data", *([None] * (v.ndim - 1)))
                   for k, v in batch.items()}
-        f = compat_shard_map(body, mesh=mesh, in_specs=(pspecs, bspecs),
+        f = jax.shard_map(body, mesh=mesh, in_specs=(pspecs, bspecs),
                           out_specs=(P(), pspecs), check_vma=False)
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lossN, gradsN = jax.jit(f)(paramsN, batch)
         assert abs(float(ref_loss - lossN)) < 3e-4, (arch, ref_loss, lossN)
         refN = relayout_tp(ref_grads, cfg, TP)
@@ -143,7 +143,7 @@ def check_tp_equivalence():
 
 
 def check_packed_aggregation():
-    mesh = compat_make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     from repro.core import secagg
 
     def body(z):
@@ -152,16 +152,16 @@ def check_packed_aggregation():
         return plain, packed
 
     z = jax.random.randint(jax.random.key(0), (4 * 1001,), 0, 16, jnp.int32)
-    f = compat_shard_map(body, mesh=mesh, in_specs=P("data"),
+    f = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
                       out_specs=(P("data"), P("data")), check_vma=False)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         plain, packed = jax.jit(f)(z)
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(packed))
     print("  packed == unpacked aggregation")
 
 
 def check_sharded_train_step():
-    mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     plan = MeshPlan(mesh=mesh, client_axes=("pod", "data"))
     cfg = get_config("gemma3-4b", reduced=True)
     shape = InputShape("t", 128, 8, "train")
@@ -171,7 +171,7 @@ def check_sharded_train_step():
         cfg, plan, mech, opt, constant(0.2), shape, packed=True,
         compute_dtype=jnp.float32,
     )
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params1 = model_lib.init_params(jax.random.key(0), cfg, tp=1)
         params = relayout_tp(params1, cfg, 2)
         params = jax.device_put(params,
@@ -206,14 +206,14 @@ def check_sharded_train_step():
 
 
 def check_sharded_decode():
-    mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     plan = MeshPlan(mesh=mesh, client_axes=("pod", "data"))
     cfg = get_config("h2o-danube-3-4b", reduced=True)
     B, CAP = 8, 64
     shape = InputShape("t", CAP, B, "decode")
     fn, specs = make_decode_step(cfg, plan, shape, compute_dtype=jnp.float32,
                                  param_dtype=jnp.float32)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params1 = model_lib.init_params(jax.random.key(0), cfg, tp=1)
         params = relayout_tp(params1, cfg, 2)
         params = jax.device_put(params,
@@ -243,7 +243,7 @@ def check_sharded_decode():
 def check_perf_variants():
     """§Perf options run and learn: int16 aggregation (exact vs int32),
     int8-compressed SP gathers (approximate), ZeRO-1 (sharded master)."""
-    mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     plan = MeshPlan(mesh=mesh, client_axes=("pod", "data"))
     cfg = get_config("gemma3-4b", reduced=True)
     shape = InputShape("t", 128, 8, "train")
@@ -258,7 +258,7 @@ def check_perf_variants():
                      ("zero1", {"zero1": True, "agg_dtype": "auto"})]:
         fn, specs = make_train_step(cfg, plan, mech, opt, lambda s: 0.2,
                                     shape, compute_dtype=jnp.float32, **kw)
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = model_lib.init_params(jax.random.key(0), cfg, tp=2)
             params = jax.device_put(
                 params, meta_lib.shardings(specs["param_meta"], mesh))
@@ -291,7 +291,7 @@ def check_flash_decoding():
     """Seq-sharded (batch=1) flash-decoding — gemma3's long_500k path — must
     reproduce the local decode exactly: KV cache sharded over the client
     axes on the SEQ dim, log-sum-exp combine via pmax/psum."""
-    mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     plan = MeshPlan(mesh=mesh, client_axes=("pod", "data"))
     cfg = get_config("gemma3-4b", reduced=True)  # has a global-attn layer
     B, CAP, PROMPT = 1, 128, 96
@@ -312,7 +312,7 @@ def check_flash_decoding():
 
     fn, specs = make_decode_step(cfg, plan, shape, compute_dtype=jnp.float32,
                                  param_dtype=jnp.float32)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.device_put(relayout_tp(params1, cfg, 2),
                                 meta_lib.shardings(specs["param_meta"], mesh))
         # re-layout local caches to the sharded metas: tp dim size 1 -> 2

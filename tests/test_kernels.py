@@ -9,6 +9,7 @@ import pytest
 from repro.core.distribution import rqm_outcome_distribution
 from repro.core.grid import RQMParams, encode_value
 from repro.core.pbm import PBMParams
+from repro.core.qmgeo import QMGeoParams
 from repro.kernels import ops, ref
 
 PARAMS = RQMParams(c=1.0, delta=1.0, m=16, q=0.42)
@@ -108,6 +109,23 @@ class TestPBMKernel:
         z = ops.pbm(jnp.full((100_000,), 0.5), jax.random.key(2), params,
                     interpret=True)
         assert abs(float(z.mean()) - 16 * (0.5 + 0.125)) < 0.05
+
+
+@pytest.mark.parametrize("name,params", [
+    ("rqm", PARAMS),
+    ("pbm", PBMParams(c=1.0, m=16, theta=0.25)),
+    ("qmgeo", QMGeoParams(c=1.0, delta=1.0, m=16, r=0.6)),
+])
+def test_offset_kernel_matches_fast_path(name, params):
+    """A shard's slice: the kernel's element-offset operand draws exactly
+    the counters the fused-jnp path draws at the same offset."""
+    x = _x((3000,), jnp.float32, seed=4)
+    key = jax.random.key(13)
+    offset = jnp.uint32(5 * 3000)
+    z_k = getattr(ops, name)(x, key, params, offset=offset, interpret=True,
+                             block_rows=8)
+    z_f = getattr(ops, f"{name}_fast")(x, key, params, offset=offset)
+    np.testing.assert_array_equal(np.asarray(z_k), np.asarray(z_f))
 
 
 class TestTreeOps:

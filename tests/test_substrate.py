@@ -1,5 +1,7 @@
 """Substrate tests: data pipelines, checkpointing, optimizers, schedules,
-federated loop integration."""
+the persistent compile cache, federated loop integration."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,7 @@ from repro.data.emnist import NUM_CLASSES, SyntheticEMNIST
 from repro.data.federated import FederatedPartition, sample_clients
 from repro.data.lm import TokenPipeline
 from repro.fed.loop import FedConfig, FedTrainer
+from repro.launch import compile_cache
 from repro.optim import adam, make_optimizer
 from repro.optim.schedules import cosine_decay, warmup_cosine
 
@@ -164,6 +167,41 @@ class TestSchedules:
         f = cosine_decay(2.0, 50, final_frac=0.1)
         assert float(f(jnp.int32(0))) == pytest.approx(2.0)
         assert float(f(jnp.int32(50))) == pytest.approx(0.2, rel=1e-3)
+
+
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def _restore_cache_config(self):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        saved = {n: getattr(jax.config, n) for n in names}
+        yield
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        compilation_cache.reset_cache()
+
+    def test_env_dir_is_used_and_receives_compiles(self, tmp_path,
+                                                  monkeypatch):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        compilation_cache.reset_cache()
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        jax.jit(lambda x: x * 7 - 2)(jnp.arange(3.0)).block_until_ready()
+        assert os.listdir(tmp_path), "no compiled program landed in the cache"
+
+    def test_default_dir_is_fixed_under_the_repo(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
 
 
 class TestFedLoop:
