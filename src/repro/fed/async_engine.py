@@ -339,9 +339,10 @@ class AsyncEngine(Engine):
                         cohort.sample_slate(cfg, tr.slate, k_sample)[0]
                     )
             if self._plain:
-                tr.flat, tr.opt_state, tr._key, z_sum, n_real = (
-                    self._round_jit(tr.flat, tr.opt_state, tr._key, data)
-                )
+                with tr.timings.scope("dispatch"):
+                    tr.flat, tr.opt_state, tr._key, z_sum, n_real = (
+                        self._round_jit(tr.flat, tr.opt_state, tr._key, data)
+                    )
             else:
                 stale = jnp.asarray(sched.staleness)
                 delivered = jnp.asarray(sched.delivered)
@@ -353,9 +354,10 @@ class AsyncEngine(Engine):
                         sched.staleness[sched.delivered]
                     )
                     args = args + (jnp.float32(disc),)
-                self._hist, tr.opt_state, tr._key, z_sum, n_real = (
-                    self._round_jit(*args)
-                )
+                with tr.timings.scope("dispatch"):
+                    self._hist, tr.opt_state, tr._key, z_sum, n_real = (
+                        self._round_jit(*args)
+                    )
                 tr.flat = self._hist[0]
             if cfg.collect_sums:
                 tr.round_sums.append(np.asarray(z_sum))

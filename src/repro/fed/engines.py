@@ -59,9 +59,10 @@ class ScanEngine(Engine):
         done = 0
         while done < n_rounds:
             step = min(tr.cfg.scan_block, n_rounds - done)
-            out = self._block_jit(
-                tr.flat, tr.opt_state, tr._key, tr.client_data, step,
-            )
+            with tr.timings.scope("dispatch"):
+                out = self._block_jit(
+                    tr.flat, tr.opt_state, tr._key, tr.client_data, step,
+                )
             tr._finish_block(out)
             done += step
         if not tr._hetero:
@@ -84,9 +85,10 @@ class PerRoundEngine(Engine):
     def advance(self, n_rounds: int):
         tr = self.tr
         for _ in range(n_rounds):
-            tr.flat, tr.opt_state, tr._key, z_sum, n_real = self._round_jit(
-                tr.flat, tr.opt_state, tr._key, tr.client_data,
-            )
+            with tr.timings.scope("dispatch"):
+                tr.flat, tr.opt_state, tr._key, z_sum, n_real = (
+                    self._round_jit(tr.flat, tr.opt_state, tr._key,
+                                    tr.client_data))
             if tr.cfg.collect_sums:
                 tr.round_sums.append(np.asarray(z_sum))
             if tr._hetero:
@@ -133,9 +135,10 @@ class HostEngine(Engine):
         return jax.tree_util.tree_map(jnp.asarray, data)
 
     def _fixed_round(self):
-        # the host loop's stages are separate dispatches, so it times the
-        # fine-grained telemetry scopes (stage/grads/encode/secure_sum/
-        # apply) the fused jitted engines cannot observe (docs/telemetry.md)
+        # the host loop's stages are separate dispatches, so it times each
+        # as a host scope (stage/grads/encode/secure_sum/apply); the jitted
+        # engines split their rounds by device scope instead
+        # (docs/telemetry.md)
         tr, cfg = self.tr, self.tr.cfg
         ids = sample_clients(tr._rng, cfg.num_clients, cfg.clients_per_round)
         with tr.timings.scope("stage"):
@@ -319,9 +322,10 @@ class ShardEngine(Engine):
                 tr.staged_bytes_total += nbytes
             else:
                 data = tr.client_data
-            out = self._block_jit(step)(
-                tr.flat, tr.opt_state, tr._key, data
-            )
+            with tr.timings.scope("dispatch"):
+                out = self._block_jit(step)(
+                    tr.flat, tr.opt_state, tr._key, data
+                )
             tr._finish_block(out)
             done += step
         if not tr._hetero:

@@ -24,6 +24,16 @@ CPU. (Without it, cross-round fusion and while-loop single-threading on
 XLA:CPU shift gradients by ~1 ULP, which RQM's randomized rounding then
 amplifies.)
 
+Each stage of a round is a device scope (``jax.named_scope``), which the
+compiler keeps in the ``op_name`` of every op it emits, so a device trace
+splits the round by stage: ``fed.grads`` (the cohort's gradient map; its
+own ops stack the gradients), ``fed.client_grad`` (one client's gradient
+and clip), ``fed.encode_sum`` (encode and participation mask; with fused
+rounds also the sum), ``fed.secure_sum`` (the cohort sum and the
+cross-shard SecAgg exchange) and ``fed.decode_apply`` (decode and the
+server step). The key split, the slate draw and the gather of the
+cohort's data lie outside them.
+
 Builders return traced-side callables; the engine classes
 (``repro.fed.engines``) own jit/shard_map wrapping and dispatch.
 """
@@ -120,16 +130,18 @@ def make_client_grad(mech, unravel, cfg, task, ctx=None):
             return gflat
 
     def client_grad(flat_params, batch):
-        if local_steps <= 1:
-            return jnp.clip(flat_grad(flat_params, batch),
-                            -mech.clip, mech.clip)
+        with jax.named_scope("fed.client_grad"):
+            if local_steps <= 1:
+                return jnp.clip(flat_grad(flat_params, batch),
+                                -mech.clip, mech.clip)
 
-        def body(flat, _):
-            return flat - local_lr * flat_grad(flat, batch), None
+            def body(flat, _):
+                return flat - local_lr * flat_grad(flat, batch), None
 
-        flat_new, _ = jax.lax.scan(body, flat_params, None, length=local_steps)
-        delta = flat_params - flat_new
-        return jnp.clip(delta, -mech.clip, mech.clip)
+            flat_new, _ = jax.lax.scan(body, flat_params, None,
+                                       length=local_steps)
+            delta = flat_params - flat_new
+            return jnp.clip(delta, -mech.clip, mech.clip)
 
     return client_grad
 
@@ -143,10 +155,14 @@ def cohort_grads(client_grad, params, batch, *, per_client_params=False):
     clients share the call — enough to change the released integers
     between the shard engine's per-chip slice and the whole cohort.
     ``per_client_params``: ``params`` carries a leading client axis (the
-    async engine's per-member parameter versions)."""
-    if per_client_params:
-        return jax.lax.map(lambda pb: client_grad(*pb), (params, batch))
-    return jax.lax.map(lambda b: client_grad(params, b), batch)
+    async engine's per-member parameter versions).
+
+    Device scope ``fed.grads``; its own ops (outside each client's
+    ``fed.client_grad``) write every gradient into the cohort's stack."""
+    with jax.named_scope("fed.grads"):
+        if per_client_params:
+            return jax.lax.map(lambda pb: client_grad(*pb), (params, batch))
+        return jax.lax.map(lambda b: client_grad(params, b), batch)
 
 
 def make_server_apply(opt, cfg, hetero):
@@ -188,34 +204,40 @@ def make_round_step(mech, cfg, opt, slate, client_grad):
         # already-clipped grads): one fused kernel call over the whole
         # (clients, dim) stack when the mechanism is kernel-backed. With
         # fused_rounds the encode and the SecAgg sum are ONE streamed
-        # reduction — the (clients, dim) encoded batch never exists; with
-        # pack_bits it leaves the reduction already as b-bit wire words
-        # (core/wire.py) for the packed decode_apply_sum to consume.
-        part = cohort.participation(cfg, valid, k_drop) if hetero else None
-        if fused:
-            z_sum = mech.quantize_sum_batch(grads, k_enc, weights=part,
-                                            pack_bits=pack_bits)
-        else:
-            z = mech.quantize_batch(grads, k_enc)
-            if hetero:
-                z = z * part.astype(z.dtype)[:, None]  # non-participants: 0
-            z_sum = jnp.sum(z, axis=0, dtype=z.dtype)  # SecAgg sum
-        if not hetero:
-            n_real = jnp.int32(cfg.clients_per_round)
-            n_dec = cfg.clients_per_round
-        else:
-            n_real = jnp.sum(part, dtype=jnp.int32)
-            # an empty round releases nothing and moves nothing
-            n_dec = jnp.maximum(n_real, 1)
-        if fused_apply:
-            new = decode_apply_sum(flat, z_sum, mech.params, n_dec, cfg.lr,
-                                   pack_bits=pack_bits)
-            new_state = opt_state
-            if hetero:
-                new = jnp.where(n_real > 0, new, flat)
-        else:
-            g_hat = mech.decode_sum(z_sum, n_dec)
-            new, new_state = apply(flat, opt_state, g_hat, n_real)
+        # reduction — the (clients, dim) encoded batch never exists, and
+        # the round has no fed.secure_sum scope; with pack_bits it leaves
+        # the reduction already as b-bit wire words (core/wire.py) for the
+        # packed decode_apply_sum to consume.
+        with jax.named_scope("fed.encode_sum"):
+            part = (cohort.participation(cfg, valid, k_drop) if hetero
+                    else None)
+            if fused:
+                z_sum = mech.quantize_sum_batch(grads, k_enc, weights=part,
+                                                pack_bits=pack_bits)
+            else:
+                z = mech.quantize_batch(grads, k_enc)
+                if hetero:
+                    z = z * part.astype(z.dtype)[:, None]  # non-participants
+        if not fused:
+            with jax.named_scope("fed.secure_sum"):
+                z_sum = jnp.sum(z, axis=0, dtype=z.dtype)
+        with jax.named_scope("fed.decode_apply"):
+            if not hetero:
+                n_real = jnp.int32(cfg.clients_per_round)
+                n_dec = cfg.clients_per_round
+            else:
+                n_real = jnp.sum(part, dtype=jnp.int32)
+                # an empty round releases nothing and moves nothing
+                n_dec = jnp.maximum(n_real, 1)
+            if fused_apply:
+                new = decode_apply_sum(flat, z_sum, mech.params, n_dec,
+                                       cfg.lr, pack_bits=pack_bits)
+                new_state = opt_state
+                if hetero:
+                    new = jnp.where(n_real > 0, new, flat)
+            else:
+                g_hat = mech.decode_sum(z_sum, n_dec)
+                new, new_state = apply(flat, opt_state, g_hat, n_real)
         new, new_state = jax.lax.optimization_barrier((new, new_state))
         if pack_bits is not None and cfg.collect_sums:
             # the collected observable stays the DENSE sum (exact unpack),
@@ -313,36 +335,33 @@ def make_shard_round_step(mech, cfg, opt, slate, shards, client_grad):
                 ids = jax.lax.dynamic_slice_in_dim(ids, j * n_per, n_per)
             batch = index_batch(data, ids)
         grads = cohort_grads(client_grad, flat, batch)
-        local = None
-        if hetero:
-            # replicated full-slate participation; each shard masks its
-            # own row slice out of the partial sum
-            part = cohort.participation(cfg, valid, k_drop)
-            local = (jax.lax.dynamic_slice_in_dim(part, j * n_per, n_per)
-                     if multi else part)
-            n_real = jnp.sum(part, dtype=jnp.int32)
-        else:
-            n_real = jnp.int32(n)
-        if fused:
-            # one streamed clip->encode->shard-local-sum: the per-shard
-            # (n_per, dim) encoded slice is never materialized, and the
-            # reduction the SecAgg boundary receives is already done —
-            # with pack_bits, already as b-bit wire words.
-            z_part = mech.quantize_sum_batch(
-                grads, k_enc, weights=local,
-                row_offset=j * n_per if multi else None,
-                total_rows=slate if multi else None,
-                pack_bits=pack_bits,
-            )
-        else:
-            z = mech.quantize_batch(
-                grads, k_enc,
-                row_offset=j * n_per if multi else None,
-                total_rows=slate if multi else None,
-            )
+        with jax.named_scope("fed.encode_sum"):
+            local = None
             if hetero:
-                z = z * local.astype(z.dtype)[:, None]
-            z_part = jnp.sum(z, axis=0, dtype=z.dtype)  # shard-local partial
+                # replicated full-slate participation; each shard masks its
+                # own row slice out of the partial sum
+                part = cohort.participation(cfg, valid, k_drop)
+                local = (jax.lax.dynamic_slice_in_dim(part, j * n_per, n_per)
+                         if multi else part)
+            if fused:
+                # one streamed clip->encode->shard-local-sum: the per-shard
+                # (n_per, dim) encoded slice is never materialized, and the
+                # reduction the SecAgg boundary receives is already done —
+                # with pack_bits, already as b-bit wire words.
+                z_part = mech.quantize_sum_batch(
+                    grads, k_enc, weights=local,
+                    row_offset=j * n_per if multi else None,
+                    total_rows=slate if multi else None,
+                    pack_bits=pack_bits,
+                )
+            else:
+                z = mech.quantize_batch(
+                    grads, k_enc,
+                    row_offset=j * n_per if multi else None,
+                    total_rows=slate if multi else None,
+                )
+                if hetero:
+                    z = z * local.astype(z.dtype)[:, None]
         # The SecAgg boundary. Packed hot path: the shard-local partials
         # are ALREADY minimal-width wire words, and int32 addition sums
         # their fields independently (field-safety checked against the
@@ -352,22 +371,27 @@ def make_shard_round_step(mech, cfg, opt, slate, shards, client_grad):
         # indices, minimal-width-packed by secure_sum_bounded when the
         # bound allows (exact either way; the float 'none' baseline has
         # bound 0 and takes the plain psum).
-        if pack_bits is not None:
-            z_sum = jax.lax.psum(z_part, "shard")
-        else:
-            z_sum = secagg.secure_sum_bounded(
-                z_part, ("shard",), bound, packed=prefer_packed
-            )
-        n_dec = jnp.maximum(n_real, 1) if hetero else n
-        if fused_apply:
-            new = decode_apply_sum(flat, z_sum, mech.params, n_dec, cfg.lr,
-                                   pack_bits=pack_bits)
-            new_state = opt_state
-            if hetero:
-                new = jnp.where(n_real > 0, new, flat)
-        else:
-            g_hat = mech.decode_sum(z_sum, n_dec)
-            new, new_state = apply(flat, opt_state, g_hat, n_real)
+        with jax.named_scope("fed.secure_sum"):
+            if not fused:
+                z_part = jnp.sum(z, axis=0, dtype=z.dtype)  # shard-local
+            if pack_bits is not None:
+                z_sum = jax.lax.psum(z_part, "shard")
+            else:
+                z_sum = secagg.secure_sum_bounded(
+                    z_part, ("shard",), bound, packed=prefer_packed
+                )
+        with jax.named_scope("fed.decode_apply"):
+            n_real = jnp.sum(part, dtype=jnp.int32) if hetero else jnp.int32(n)
+            n_dec = jnp.maximum(n_real, 1) if hetero else n
+            if fused_apply:
+                new = decode_apply_sum(flat, z_sum, mech.params, n_dec,
+                                       cfg.lr, pack_bits=pack_bits)
+                new_state = opt_state
+                if hetero:
+                    new = jnp.where(n_real > 0, new, flat)
+            else:
+                g_hat = mech.decode_sum(z_sum, n_dec)
+                new, new_state = apply(flat, opt_state, g_hat, n_real)
         new, new_state = jax.lax.optimization_barrier((new, new_state))
         if pack_bits is not None and cfg.collect_sums:
             # collected observable = the DENSE sum (exact unpack); dead

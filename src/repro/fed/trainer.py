@@ -113,10 +113,7 @@ class FedTrainer:
         # cohort — the SecAgg sum spans every shard, so the mechanism's
         # amplification-by-aggregation sees all participants, never the
         # per-shard slice.
-        self._per_round_eps = np.asarray([
-            mech.per_round_epsilon(fed_cfg.clients_per_round, a)
-            for a in fed_cfg.accountant_alphas
-        ])
+        self._per_round_eps = self._exact_eps(fed_cfg.clients_per_round)
         self._eps_by_n = {fed_cfg.clients_per_round: self._per_round_eps}
         if self.engine.stages_population and fed_cfg.staging != "stream":
             with self.timings.scope("stage"):
@@ -234,14 +231,23 @@ class FedTrainer:
         self.opt_state = jax.tree_util.tree_map(put, self.opt_state)
 
     def _finish_block(self, out):
-        """Absorb one jitted block's outputs (blocked engines)."""
-        self.flat, self.opt_state, self._key, sums, ns = out
-        if self.cfg.collect_sums:
-            self.round_sums.extend(np.asarray(sums))
-        if self._hetero:
-            self._account_realized(np.asarray(ns))
+        """Absorb one jitted block's outputs (blocked engines). Reading the
+        collected sums or the realized counts back waits for the block."""
+        with self.timings.scope("finish_block"):
+            self.flat, self.opt_state, self._key, sums, ns = out
+            if self.cfg.collect_sums:
+                self.round_sums.extend(np.asarray(sums))
+            if self._hetero:
+                self._account_realized(np.asarray(ns))
 
     # -- privacy accounting -------------------------------------------------
+    def _exact_eps(self, n: int) -> np.ndarray:
+        """One exact evaluation of the per-round eps vector at cohort size
+        n; the "eps_eval" count in timings is how many a run made."""
+        with self.timings.scope("eps_eval"):
+            return np.asarray([self.mech.per_round_epsilon(n, a)
+                               for a in self.cfg.accountant_alphas])
+
     def _eps_vector(self, n: int) -> np.ndarray:
         """Exact per-round eps vector (over cfg.accountant_alphas) for a
         realized cohort of n clients. Memoized per size; each distinct size
@@ -253,25 +259,24 @@ class FedTrainer:
             if n <= 0:
                 v = np.zeros(len(self.cfg.accountant_alphas))
             else:
-                v = np.asarray([
-                    self.mech.per_round_epsilon(n, a)
-                    for a in self.cfg.accountant_alphas
-                ])
+                v = self._exact_eps(n)
             self._eps_by_n[n] = v
         return self._eps_by_n[n]
 
     def _account(self, n_rounds: int):
         """Fixed-cohort composition: every round at clients_per_round."""
-        for _ in range(n_rounds):
-            self.realized_n.append(self.cfg.clients_per_round)
-            self.accountant.step(self._per_round_eps)
+        with self.timings.scope("account"):
+            for _ in range(n_rounds):
+                self.realized_n.append(self.cfg.clients_per_round)
+                self.accountant.step(self._per_round_eps)
 
     def _account_realized(self, ns) -> None:
         """Heterogeneous composition: each round at its REALIZED size."""
-        for n in np.asarray(ns).reshape(-1):
-            n = int(n)
-            self.realized_n.append(n)
-            self.accountant.step(self._eps_vector(n))
+        with self.timings.scope("account"):
+            for n in np.asarray(ns).reshape(-1):
+                n = int(n)
+                self.realized_n.append(n)
+                self.accountant.step(self._eps_vector(n))
 
     def budget_spent(self) -> tuple:
         """(eps spent at cfg.budget_delta, remaining eps) — requires

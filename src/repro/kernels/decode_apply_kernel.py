@@ -54,6 +54,7 @@ def decode_apply_2d(w, z_sum, params: RQMParams, n: int, lr: float,
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), w.dtype),
         interpret=interpret,
+        name="decode_apply",
     )(w, z_sum)
 
 
@@ -125,6 +126,7 @@ def decode_apply_sum_2d(w, z_sum, params, n: int, lr: float,
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), w.dtype),
         interpret=interpret,
+        name="decode_apply_sum",
     )(w, z_sum)
 
 

@@ -134,6 +134,7 @@ def round_sum_2d(x, w, seed, row_offset, encode, params, *, dim: int,
         out_specs=pl.BlockSpec((None, 1, LANE), lambda c, r: (c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((dim_p // LANE, 1, LANE), jnp.int32),
         interpret=interpret,
+        name="round_sum",
     )(seed.reshape(1, 1), row_offset.reshape(1, 1), x, w)
 
 
@@ -266,6 +267,7 @@ def round_sum_packed_2d(x, w, seed, row_offset, encode, params, *,
         out_specs=pl.BlockSpec((None, 1, LANE), lambda i, f, r: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((wb, 1, LANE), jnp.int32),
         interpret=interpret,
+        name="round_sum_packed",
     )(seed.reshape(1, 1), row_offset.reshape(1, 1), x, w)
 
 

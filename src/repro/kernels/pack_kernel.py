@@ -82,6 +82,7 @@ def pack_flat(z, bits: int, *, interpret: bool = False):
         out_specs=pl.BlockSpec((None, 1, LANE), lambda i, f: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((wb, 1, LANE), jnp.int32),
         interpret=interpret,
+        name="pack_words",
     )(z2)
     return out.reshape(-1)
 
@@ -115,6 +116,7 @@ def unpack_flat(words, bits: int, n: int, *, interpret: bool = False):
         out_specs=pl.BlockSpec((None, 1, LANE), lambda f, i: (f * wb + i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((k * wb, 1, LANE), jnp.int32),
         interpret=interpret,
+        name="unpack_words",
     )(w2)
     return out.reshape(-1)[:n]
 
@@ -176,5 +178,6 @@ def unpack_decode_apply(w_flat, words, params, n: int, lr: float, *,
         out_shape=jax.ShapeDtypeStruct((k * wb, block_rows, LANE),
                                        w_flat.dtype),
         interpret=interpret,
+        name="unpack_decode_apply",
     )(w3, z3)
     return out.reshape(-1)[:dim]

@@ -77,4 +77,5 @@ def pbm_quantize_2d(
         out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
         interpret=interpret,
+        name="pbm_encode",
     )(seed.reshape(1, 1), offset.reshape(1, 1), x)

@@ -10,8 +10,10 @@ Three pieces (docs/telemetry.md has the full guide):
   * ``emit``    — ``RoundEmitter``, the single decode-apply-boundary
     hook: accounted rounds -> schema-stable records whose eps_spent /
     realized_n series are bit-identical to the accountant's history.
-  * ``timing``  — wall-clock ``Timings`` scopes
-    (stage / encode / secure_sum / apply / round_block).
+  * ``timing``  — ``Timings`` scopes: host wall-clock per scope, each
+    also a ``fed.<scope>`` span in a profiler trace (stage / round_block /
+    dispatch / finish_block / account / eps_eval, and the host engine's
+    and aggregator's stages).
 
 Every ``FedTrainer`` run emits through this plane (``FedConfig.track``
 or the ``tracker=`` argument); ``launch/aggregator.py`` — the

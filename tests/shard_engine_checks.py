@@ -15,7 +15,10 @@ Checks (the ISSUE-3 acceptance contract on a 4-device mesh):
   5. per-round epsilon accounts the FULL cross-shard cohort, not n/shards;
   6. under Poisson subsampling + dropout (ISSUE 4) the 4-shard engine
      realizes EXACTLY the scan engine's cohorts — same per-round realized
-     sizes, encoded sums, parameters, and accounted eps sequence.
+     sizes, encoded sums, parameters, and accounted eps sequence;
+  7. the 4-shard round block carries the five device scopes (fed.grads,
+     fed.client_grad, fed.encode_sum, fed.secure_sum, fed.decode_apply),
+     unfused and fused — the cross-shard exchange under fed.secure_sum.
 """
 import os
 
@@ -113,6 +116,24 @@ def check_full_cohort_epsilon(shard):
     print("  per_round_epsilon uses the full cross-shard cohort n, not n/S")
 
 
+def check_round_scopes():
+    import re
+
+    five = {"fed.grads", "fed.client_grad", "fed.encode_sum",
+            "fed.secure_sum", "fed.decode_apply"}
+    for fused in (False, True):
+        tr = FedTrainer(make_mechanism("rqm", c=0.05),
+                        FedConfig(engine="shard", shards=4, fused_rounds=fused,
+                                  **SMALL))
+        text = tr.engine._block_jit(2).lower(
+            tr.flat, tr.opt_state, tr._key, tr.client_data,
+        ).as_text(debug_info=True)
+        scopes = set(re.findall(r"fed\.[a-z_]+", text))
+        assert scopes == five, (fused, scopes)
+    print("  4-shard round block carries the five device scopes "
+          "(unfused and fused)")
+
+
 if __name__ == "__main__":
     import sys
 
@@ -125,4 +146,5 @@ if __name__ == "__main__":
     check_none_mechanism_allclose()
     check_full_cohort_epsilon(shard)
     check_subsampled_cohort_parity()
+    check_round_scopes()
     print("ALL SHARD ENGINE CHECKS PASS")

@@ -12,14 +12,16 @@ that is described, not attached, at the widths the lanes run:
     74,112 words — the EMNIST word count rounded up to whole lanes).
 
 Each test asserts the compiled program holds the kernel as a
-``tpu_custom_call``. The topology is described inside a module fixture,
-never at import (one process may load the TPU library at a time; under
-pytest-xdist only the worker given this file loads it). The persistent
+``tpu_custom_call`` under the ``name`` its ``pallas_call`` gives, which is
+what a device trace calls the op. The topology is described inside a
+module fixture, never at import (one process may load the TPU library at
+a time; under pytest-xdist only the worker given this file loads it). The persistent
 compilation cache is off here: a compile for a described chip cannot be
 read back without one.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,9 +80,17 @@ def spec(topo):
                                                      sharding=one_chip)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel: str):
+    """Compile ``fn`` for the described chip; every Pallas kernel in the
+    compiled program must be the one named ``kernel`` (the instruction
+    takes the ``pallas_call``'s ``name``, which a device trace shows)."""
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls, "no Pallas kernel in the program"
+    for line in calls:
+        instr = line.split(" = ", 1)[0].split()[-1].lstrip("%")
+        assert re.fullmatch(rf"{kernel}(\.\d+)?", instr), line[:160]
 
 
 @pytest.mark.parametrize("name", sorted(ENCODERS))
@@ -98,7 +108,7 @@ def test_encode_kernel(spec, name):
                            block_rows=block_rows)
 
     _compile(encode, spec(EMNIST, jnp.float32), spec((1, 1), jnp.uint32),
-             spec((), jnp.uint32))
+             spec((), jnp.uint32), kernel=f"{name}_encode")
 
 
 @pytest.mark.parametrize("shape", [EMNIST, LM], ids=["emnist", "lm"])
@@ -113,7 +123,8 @@ def test_round_sum(spec, shape):
         params=RQM, dim=dim, block_rows=block_rows)
     _compile(fn, spec((rows_p, dim_p), jnp.float32),
              spec((rows_p, LANE), jnp.int32),
-             spec((1, 1), jnp.uint32), spec((1, 1), jnp.uint32))
+             spec((1, 1), jnp.uint32), spec((1, 1), jnp.uint32),
+             kernel="round_sum")
 
 
 def test_round_sum_packed(spec):
@@ -129,18 +140,19 @@ def test_round_sum_packed(spec):
         dim=ALIGNED_DIM, words=words, bits=BITS, block_rows=block_rows)
     _compile(fn, spec((rows, fields * words), jnp.float32),
              spec((rows, LANE), jnp.int32),
-             spec((1, 1), jnp.uint32), spec((1, 1), jnp.uint32))
+             spec((1, 1), jnp.uint32), spec((1, 1), jnp.uint32),
+             kernel="round_sum_packed")
 
 
 def test_pack_flat(spec):
     _compile(lambda z: pack_kernel.pack_flat(z, BITS),
-             spec((ALIGNED_DIM,), jnp.int32))
+             spec((ALIGNED_DIM,), jnp.int32), kernel="pack_words")
 
 
 def test_unpack_flat(spec):
     words = wire.packed_words(ALIGNED_DIM, BITS)
     _compile(lambda w: pack_kernel.unpack_flat(w, BITS, ALIGNED_DIM),
-             spec((words,), jnp.int32))
+             spec((words,), jnp.int32), kernel="unpack_words")
 
 
 def test_decode_apply_sum(spec):
@@ -154,7 +166,24 @@ def test_decode_apply_sum(spec):
         return decode_apply_kernel.decode_apply_sum_2d(
             w2, z2, RQM, EMNIST[0], 0.5, block_rows=block_rows)
 
-    _compile(apply, spec((dim,), jnp.float32), spec((dim,), jnp.int32))
+    _compile(apply, spec((dim,), jnp.float32), spec((dim,), jnp.int32),
+             kernel="decode_apply_sum")
+
+
+def test_decode_apply(spec):
+    """The folded decode+apply kernel (two scalars a tile) at the EMNIST
+    dim."""
+    dim = EMNIST[1]
+    block_rows = pick_block_rows(dim)
+
+    def apply(w, z):
+        w2, _, _ = tile_flat(w, block_rows)
+        z2, _, _ = tile_flat(z, block_rows)
+        return decode_apply_kernel.decode_apply_2d(
+            w2, z2, RQM, EMNIST[0], 0.5, block_rows=block_rows)
+
+    _compile(apply, spec((dim,), jnp.float32), spec((dim,), jnp.int32),
+             kernel="decode_apply")
 
 
 @pytest.mark.parametrize("dim", [ALIGNED_DIM, ALIGNED8_DIM],
@@ -170,4 +199,5 @@ def test_unpack_decode_apply(spec, dim):
         assert out is not None, "geometry fell back to the jnp codec"
         return out
 
-    _compile(apply, spec((dim,), jnp.float32), spec((words,), jnp.int32))
+    _compile(apply, spec((dim,), jnp.float32), spec((words,), jnp.int32),
+             kernel="unpack_decode_apply")
