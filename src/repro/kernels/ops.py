@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles arbitrary input shapes (flatten -> pad to (rows, 128) tiles ->
-kernel -> slice -> reshape), key->seed derivation, interpret-mode fallback
-on CPU, and pytree mapping for whole gradient trees.
+Handles arbitrary input shapes (a leaf: flatten -> pad to (rows, 128)
+tiles -> kernel -> slice -> reshape; a stacked batch: the row-native
+kernel on the (clients, dim) array as it is), key->seed derivation,
+interpret-mode fallback on CPU, and pytree mapping for whole gradient
+trees.
 
 Every mechanism kernel gets the same three entry points, built once by
 ``_make_fast_ops`` from its (pallas ``*_quantize_2d``, element-wise
@@ -17,12 +19,17 @@ Every mechanism kernel gets the same three entry points, built once by
     this is the hot path on CPU and what the dry-run lowers — pallas
     interpret mode would unroll its grid into a python loop, which is both
     slow and unrepresentative in compiled HLO.
-  * ``<name>_batch(x, key, params, row_offset=...)`` — ``_fast`` restricted
-    to a stacked ``(clients, dim)`` batch, the shape the federated round
-    engine produces: one fused invocation whose RNG spans the flattened
-    batch, so every client row draws independent randomness from one
-    per-round seed and the output inherits the kernel<->mechanism parity
-    contract on the flattened input (see kernels/ref.py).
+  * ``<name>_batch(x, key, params, row_offset=...)`` — the encode of a
+    stacked ``(clients, dim)`` batch, the shape the federated round
+    engine produces: one invocation whose RNG spans the flattened batch
+    (element ``(r, c)`` draws counter ``(row_offset + r) * dim + c``), so
+    every client row draws independent randomness from one per-round seed
+    and the output inherits the kernel<->mechanism parity contract on the
+    flattened input (see kernels/ref.py). On a TPU it runs the row-native
+    kernel (``fused_round_kernel.encode_rows_2d``, named
+    ``<name>_encode_rows``) over the batch in its own layout, so the
+    stack is never flattened, padded or relaid; elsewhere ``_fast``'s jnp
+    twin on the flattened batch — the same integers.
   * ``<name>_round_sum(x, key, params, weights=..., row_offset=...,
     pack_bits=...)`` — the fused ROUND: clip -> encode -> weighted column
     sum streamed through VMEM-sized tiles (kernels/fused_round_kernel.py),
@@ -60,10 +67,10 @@ def _interpret_default() -> bool:
 
 
 def _force_interpret() -> bool:
-    """CPU-test hook: REPRO_PALLAS_INTERPRET=1 routes the fused round-sum
-    path through the Pallas kernel in interpret mode, so the kernel BODY
-    (not just the fused-jnp twin) is exercised on CPU-only runners. On a
-    TPU the hook is ignored: kernels there always compile."""
+    """CPU-test hook: REPRO_PALLAS_INTERPRET=1 routes the batch encode and
+    the fused round-sum through their Pallas kernels in interpret mode, so
+    the kernel BODY (not just the fused-jnp twin) is exercised on CPU-only
+    runners. On a TPU the hook is ignored: kernels there always compile."""
     return (jax.default_backend() != "tpu"
             and os.environ.get("REPRO_PALLAS_INTERPRET", "") not in ("", "0"))
 
@@ -139,19 +146,30 @@ def _make_fast_ops(quantize_2d, block_fn, name: str):
         offset = jnp.asarray(0 if offset is None else offset).astype(jnp.uint32)
         return _flat_jnp(x.reshape(-1), seed, offset, params).reshape(x.shape)
 
+    @functools.partial(jax.jit, static_argnames=("params", "interpret"))
+    def _rows(x, seed, row_offset, params, interpret: bool):
+        return fused_round_kernel.encode_rows_2d(
+            x, seed, row_offset, name, params, interpret=interpret)
+
     def batch(x, key, params, *, row_offset=None):
         """Kernel-backed encode for a stacked ``(clients, dim)`` batch.
 
         row_offset: optional (traced) row offset — this batch plays rows
         ``[row_offset, row_offset + clients)`` of a larger stacked batch
-        encoded with the same key (the shard engine's per-shard slice)."""
+        encoded with the same key (the shard engine's per-shard slice).
+        On a TPU (or under REPRO_PALLAS_INTERPRET=1) the row-native kernel
+        encodes the stack in its own layout; elsewhere the flat jnp twin
+        runs on ``x.reshape(-1)`` — the same integers."""
         if x.ndim != 2:
             raise ValueError(f"{name}_batch expects (clients, dim), got {x.shape}")
-        offset = None
-        if row_offset is not None:
-            offset = (jnp.asarray(row_offset).astype(jnp.uint32)
-                      * jnp.uint32(x.shape[1]))
-        return fast(x, key, params, offset=offset)
+        rows_offset = jnp.asarray(0 if row_offset is None
+                                  else row_offset).astype(jnp.uint32)
+        on_tpu = jax.default_backend() == "tpu"
+        if on_tpu or _force_interpret():
+            return _rows(x, key_to_seed(key), rows_offset, params,
+                         not on_tpu)
+        return fast(x, key, params,
+                    offset=rows_offset * jnp.uint32(x.shape[1]))
 
     pallas.__name__, fast.__name__, batch.__name__ = (
         name, f"{name}_fast", f"{name}_batch")

@@ -8,6 +8,8 @@ that is described, not attached, at the widths the lanes run:
 
   * the EMNIST CNN at the paper cohort: 40 clients x dim 222,030;
   * the federated LM lane: 8 clients x dim 1,096,032;
+  * the benchmark's mamba2-370m cohort: 8 clients x dim 129,385,856
+    (the row-native batch encode);
   * the packed wire kernels at a lane-aligned word count (10-bit fields,
     74,112 words — the EMNIST word count rounded up to whole lanes).
 
@@ -40,6 +42,7 @@ from repro.kernels.rqm_kernel import LANE, pick_block_rows, rqm_quantize_2d
 
 EMNIST = (40, 222_030)     # paper cohort x EMNIST CNN dim
 LM = (8, 1_096_032)        # lm lane cohort x its model dim
+MAMBA2 = (8, 129_385_856)  # the benchmark's mamba2-370m cohort x dim
 BITS = 10                  # wire width of a 40-client RQM m=16 sum
 ALIGNED_DIM = 3 * 74_112 - 2    # -> 74,112 packed words = 579 lanes
 ALIGNED8_DIM = 3 * 73_728       # -> 576 lanes: 8-row unpack blocks
@@ -95,9 +98,9 @@ def _compile(fn, *args, kernel: str):
 
 @pytest.mark.parametrize("name", sorted(ENCODERS))
 def test_encode_kernel(spec, name):
-    """The materialized encode (fused_rounds=False) at the paper cohort:
-    the whole (40, 222030) batch flattened into (rows, 128) tiles, with a
-    traced element offset (a shard's slice of a larger cohort)."""
+    """The flat encode of a 1-D leaf, at the paper cohort's size: the
+    (40, 222030) elements flattened into (rows, 128) tiles, with a traced
+    element offset."""
     quantize_2d, params = ENCODERS[name]
     n = EMNIST[0] * EMNIST[1]
     block_rows = pick_block_rows(n)
@@ -109,6 +112,24 @@ def test_encode_kernel(spec, name):
 
     _compile(encode, spec(EMNIST, jnp.float32), spec((1, 1), jnp.uint32),
              spec((), jnp.uint32), kernel=f"{name}_encode")
+
+
+@pytest.mark.parametrize("shape", [EMNIST, LM, MAMBA2],
+                         ids=["emnist", "lm", "mamba2"])
+@pytest.mark.parametrize("name", sorted(ENCODERS))
+def test_encode_rows_kernel(spec, name, shape):
+    """The materialized batch encode as the engines run it on the chip:
+    the (clients, dim) stack in its own layout, an edge column block
+    where dim is not a multiple of the block width, and a traced row
+    offset (a shard's slice of a larger cohort)."""
+    params = ENCODERS[name][1]
+
+    def encode(x, seed, row_offset):
+        return fused_round_kernel.encode_rows_2d(x, seed, row_offset, name,
+                                                 params)
+
+    _compile(encode, spec(shape, jnp.float32), spec((), jnp.uint32),
+             spec((), jnp.uint32), kernel=f"{name}_encode_rows")
 
 
 @pytest.mark.parametrize("shape", [EMNIST, LM], ids=["emnist", "lm"])
