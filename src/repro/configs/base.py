@@ -2,8 +2,9 @@
 
 A ModelConfig is a complete static description of one architecture: per-layer
 block kinds (attention / ssm / shared-attention), attention geometry
-(GQA / sliding-window / local:global mix / partial rotary), MLP kind, MoE
-and SSM specs, vocab, and the modality-frontend stub for VLM/audio archs.
+(GQA / sliding-window / local:global mix / partial or no rotary), MLP kind
+(and whether SSM layers carry one), MoE and SSM specs, vocab, the muP-style
+multipliers, and the modality-frontend stub for VLM/audio archs.
 
 ``reduced()`` derives the CPU smoke-test variant of the same family
 (<=2 layers, d_model<=512, <=4 experts) per the assignment contract.
@@ -51,16 +52,24 @@ class ModelConfig:
     vocab_size: int
     layers: tuple[LayerSpec, ...]
     mlp_kind: Optional[str] = "swiglu"  # None for pure-SSM archs
-    rotary_frac: float = 1.0
+    rotary_frac: float = 1.0  # 0.0: no position embedding (NoPE)
     qkv_bias: bool = False
     moe: Optional[MoESpec] = None
     ssm: Optional[SSMSpec] = None
     shared_attn: bool = False  # zamba2: one attention block shared by layers
     shared_d_ff: int = 0
     frontend: Optional[FrontendSpec] = None
-    norm_eps: float = 1e-6
+    norm_eps: float = 1e-6  # every RMSNorm, the SSM's gated one included
     vocab_pad_to: int = 128  # vocab padded to a multiple of this * tp
-    tie_embeddings: bool = False
+    tie_embeddings: bool = False  # the LM head is the embedding transposed
+    # granite-4.0-h: every SSM layer is followed by norm2 and an MLP
+    ssm_mlp: bool = False
+    # muP-style multipliers: x = embedding_multiplier * embed(ids); each
+    # sublayer adds residual_multiplier * out; logits = h @ head / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attn_scale: Optional[float] = None  # softmax scale; None = 1/sqrt(head_dim)
     q_chunk: int = 256
     subquadratic: bool = False  # eligible for long_500k decode
 
@@ -79,6 +88,7 @@ class ModelConfig:
             window=layer.window,
             qkv_bias=self.qkv_bias,
             q_chunk=self.q_chunk,
+            scale_override=self.attn_scale,
         )
 
     def active_params_per_token_factor(self) -> float:

@@ -14,6 +14,7 @@ ARCH_IDS = (
     "qwen3-moe-30b-a3b",
     "pixtral-12b",
     "chatglm3-6b",
+    "granite-4.0-h-micro",
 )
 
 _MODULES = {
@@ -27,6 +28,7 @@ _MODULES = {
     "qwen3-moe-30b-a3b": "qwen3_moe",
     "pixtral-12b": "pixtral_12b",
     "chatglm3-6b": "chatglm3_6b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

@@ -37,7 +37,7 @@ class AttentionSpec:
     num_kv_heads: int
     head_dim: int
     rope_theta: float = 10000.0
-    rotary_frac: float = 1.0
+    rotary_frac: float = 1.0  # 0.0: NoPE
     window: Optional[int] = None  # sliding-window size; None = full causal
     qkv_bias: bool = False
     q_chunk: int = 256  # query-block size for the chunked train/prefill path
@@ -99,7 +99,8 @@ def param_meta(spec: AttentionSpec, tp: int, dtype=jnp.float32):
 
 
 def _project_qkv(params, spec: AttentionSpec, sh: AttnSharding, x, positions):
-    """x: (B, S, D) -> q (B,S,ql,hd), k,v (B,S,kvl,hd), rope applied."""
+    """x: (B, S, D) -> q (B,S,ql,hd), k,v (B,S,kvl,hd), rope applied
+    (none where ``rotary_frac`` is 0: NoPE)."""
     hd = spec.head_dim
     wq = squeeze_tp(params["wq"], 1)
     wkv = squeeze_tp(params["wkv"], 1)
@@ -112,8 +113,9 @@ def _project_qkv(params, spec: AttentionSpec, sh: AttnSharding, x, positions):
     q = q.reshape(B, S, sh.q_local, hd)
     kv = kv.reshape(B, S, sh.kv_local, 2, hd)
     k, v = kv[..., 0, :], kv[..., 1, :]
-    q = apply_rope(q, positions, spec.rope_theta, spec.rotary_frac)
-    k = apply_rope(k, positions, spec.rope_theta, spec.rotary_frac)
+    if spec.rotary_frac > 0:
+        q = apply_rope(q, positions, spec.rope_theta, spec.rotary_frac)
+        k = apply_rope(k, positions, spec.rope_theta, spec.rotary_frac)
     return q, k, v
 
 

@@ -4,10 +4,23 @@ ctx.tp == 1 — identical code path, collectives are no-ops).
 
 Parameter pytree (mirrored by param_meta/init_params):
   embed:      (tp, V_l, D)  vocab-parallel table
-  layers[i]:  {"norm1", "attn"/"ssm", ["norm2", "mlp"/"moe"]}
+  layers[i]:  {"norm1", "attn"/"ssm", ["norm2", "mlp"/"moe"]} (an ssm layer
+              carries norm2 and an MLP where cfg.ssm_mlp)
   shared:     one attention+MLP block reused by all 'shared_attn' layers
   final_norm: (D,)
-  lm_head:    (D, tp, V_l) column-parallel
+  lm_head:    (D, tp, V_l) column-parallel; absent where cfg.tie_embeddings
+              (the head is then the embedding table transposed)
+
+Each sublayer opens a device scope (``jax.named_scope``), so a device trace
+splits a client's gradient by layer kind: ``fed.layer.ssm`` and
+``fed.layer.attn`` (norm1, the mixer, its residual), ``fed.layer.mlp`` or
+``fed.layer.moe`` (norm2, the MLP or experts, its residual) and
+``fed.layer.head`` (the embedding, the final norm and the LM head's loss).
+Under ``jax.grad`` a scope's name stays bare (what ``bench/scopes.py`` reads)
+only where it opens inside a recomputed region: every layer when the loss
+runs with ``remat``, and the LM head's loss chunks (always recomputed);
+elsewhere the forward ops read ``jvp(fed.layer.*)`` and a split counts them
+under the enclosing ``fed.client_grad``.
 """
 from __future__ import annotations
 
@@ -31,14 +44,17 @@ from repro.models.meta import Meta
 
 def _layer_init(key, cfg: ModelConfig, layer: LayerSpec, tp: int, dtype):
     D = cfg.d_model
+    if layer.kind == "shared_attn":
+        return {}  # params live in the shared block
     p = {"norm1": jnp.zeros((D,), dtype)}
     if layer.kind == "ssm":
         p["ssm"] = ssm.init_params(key, cfg.ssm, tp, dtype)
-        return p
-    if layer.kind == "shared_attn":
-        return {}  # params live in the shared block
-    k1, k2 = jax.random.split(key)
-    p["attn"] = attention.init_params(k1, cfg.attn_spec(layer), tp, dtype)
+        if not cfg.ssm_mlp:
+            return p
+        k2 = jax.random.fold_in(key, 1)
+    else:
+        k1, k2 = jax.random.split(key)
+        p["attn"] = attention.init_params(k1, cfg.attn_spec(layer), tp, dtype)
     p["norm2"] = jnp.zeros((D,), dtype)
     if cfg.moe is not None:
         p["moe"] = moe.init_params(k2, cfg.moe, tp, dtype)
@@ -49,13 +65,15 @@ def _layer_init(key, cfg: ModelConfig, layer: LayerSpec, tp: int, dtype):
 
 def _layer_meta(cfg: ModelConfig, layer: LayerSpec, tp: int, dtype):
     D = cfg.d_model
+    if layer.kind == "shared_attn":
+        return {}
     m = {"norm1": Meta((D,), dtype, P(None), tp)}
     if layer.kind == "ssm":
         m["ssm"] = ssm.param_meta(cfg.ssm, tp, dtype)
-        return m
-    if layer.kind == "shared_attn":
-        return {}
-    m["attn"] = attention.param_meta(cfg.attn_spec(layer), tp, dtype)
+        if not cfg.ssm_mlp:
+            return m
+    else:
+        m["attn"] = attention.param_meta(cfg.attn_spec(layer), tp, dtype)
     m["norm2"] = Meta((D,), dtype, P(None), tp)
     if cfg.moe is not None:
         m["moe"] = moe.param_meta(cfg.moe, tp, dtype)
@@ -82,8 +100,10 @@ def init_params(key, cfg: ModelConfig, tp: int = 1, dtype=jnp.float32):
             for i, layer in enumerate(cfg.layers)
         ),
         "final_norm": jnp.zeros((D,), dtype),
-        "lm_head": dense_init(keys[-1], (D, tp, V // tp), in_axis=0, dtype=dtype),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense_init(keys[-1], (D, tp, V // tp), in_axis=0,
+                                       dtype=dtype)
     if cfg.shared_attn:
         ks1, ks2 = jax.random.split(keys[-2])
         spec = cfg.attn_spec(_shared_layerspec(cfg))
@@ -105,8 +125,9 @@ def param_meta(cfg: ModelConfig, tp: int = 1, dtype=jnp.float32):
             _layer_meta(cfg, layer, tp, dtype) for layer in cfg.layers
         ),
         "final_norm": Meta((D,), dtype, P(None), tp),
-        "lm_head": Meta((D, tp, V // tp), dtype, P(None, "model", None), 1),
     }
+    if not cfg.tie_embeddings:
+        m["lm_head"] = Meta((D, tp, V // tp), dtype, P(None, "model", None), 1)
     if cfg.shared_attn:
         spec = cfg.attn_spec(_shared_layerspec(cfg))
         m["shared"] = {
@@ -124,7 +145,8 @@ def param_meta(cfg: ModelConfig, tp: int = 1, dtype=jnp.float32):
 
 
 def embed(params, cfg: ModelConfig, ctx: ParallelCtx, tokens):
-    """tokens (B, S) -> (B, S, D). Local table rows + psum over model."""
+    """tokens (B, S) -> (B, S, D). Local table rows + psum over model, times
+    ``cfg.embedding_multiplier``."""
     table = squeeze_tp(params["embed"], 0)  # (V_l, D)
     v_l = table.shape[0]
     lo = ctx.model_index() * v_l
@@ -132,7 +154,18 @@ def embed(params, cfg: ModelConfig, ctx: ParallelCtx, tokens):
     valid = (ids >= 0) & (ids < v_l)
     emb = jnp.take(table, jnp.clip(ids, 0, v_l - 1), axis=0)
     emb = jnp.where(valid[..., None], emb, 0)
+    if cfg.embedding_multiplier != 1.0:
+        emb = emb * cfg.embedding_multiplier
     return ctx.psum_model(emb)
+
+
+def _head(params):
+    """The local LM head (D, V_l): its own matrix, or the vocab-parallel
+    embedding table transposed where the config ties them (init_params then
+    makes no ``lm_head``)."""
+    if "lm_head" in params:
+        return squeeze_tp(params["lm_head"], 1)
+    return squeeze_tp(params["embed"], 0).T
 
 
 def lm_head_loss(params, cfg: ModelConfig, ctx: ParallelCtx, h, labels,
@@ -146,7 +179,7 @@ def lm_head_loss(params, cfg: ModelConfig, ctx: ParallelCtx, h, labels,
     the log-sum-exp / target-logit terms combine with pmax/psum over the
     model axis.
     """
-    head = squeeze_tp(params["lm_head"], 1)  # (D, V_l)
+    head = _head(params)  # (D, V_l)
     v_l = head.shape[1]
     lo = ctx.model_index() * v_l
     B, S, _ = h.shape
@@ -157,22 +190,27 @@ def lm_head_loss(params, cfg: ModelConfig, ctx: ParallelCtx, h, labels,
 
     def chunk_loss(args):
         h_c, labels_c = args  # (B, cs, D), (B, cs)
-        logits = jnp.einsum("bsd,dv->bsv", h_c, head.astype(h_c.dtype)).astype(jnp.float32)
-        # stop_gradient BEFORE the pmax: pmax has no differentiation rule,
-        # and the max is only a stabilization shift anyway.
-        mx = ctx.pmax_model(
-            jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
-        )
-        sumexp = jnp.sum(jnp.exp(logits - mx), axis=-1)
-        lse = jnp.log(ctx.psum_model(sumexp)) + mx[..., 0]
-        ids = labels_c - lo
-        valid = (ids >= 0) & (ids < v_l)
-        tgt_local = jnp.take_along_axis(
-            logits, jnp.clip(ids, 0, v_l - 1)[..., None], axis=-1
-        )[..., 0]
-        tgt = ctx.psum_model(jnp.where(valid, tgt_local, 0.0))
-        mask = (labels_c >= 0).astype(jnp.float32)
-        return jnp.sum((lse - tgt) * mask)
+        # the scope opens inside the recomputed chunk, so that the head's
+        # backward ops carry it too (bench/scopes.py reads the bare name)
+        with jax.named_scope("fed.layer.head"):
+            logits = jnp.einsum("bsd,dv->bsv", h_c, head.astype(h_c.dtype)).astype(jnp.float32)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
+            # stop_gradient BEFORE the pmax: pmax has no differentiation
+            # rule, and the max is only a stabilization shift anyway.
+            mx = ctx.pmax_model(
+                jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
+            )
+            sumexp = jnp.sum(jnp.exp(logits - mx), axis=-1)
+            lse = jnp.log(ctx.psum_model(sumexp)) + mx[..., 0]
+            ids = labels_c - lo
+            valid = (ids >= 0) & (ids < v_l)
+            tgt_local = jnp.take_along_axis(
+                logits, jnp.clip(ids, 0, v_l - 1)[..., None], axis=-1
+            )[..., 0]
+            tgt = ctx.psum_model(jnp.where(valid, tgt_local, 0.0))
+            mask = (labels_c >= 0).astype(jnp.float32)
+            return jnp.sum((lse - tgt) * mask)
 
     h_c = h.reshape(B, n_chunks, cs, -1).transpose(1, 0, 2, 3)
     l_c = labels.reshape(B, n_chunks, cs).transpose(1, 0, 2)
@@ -184,8 +222,9 @@ def lm_head_loss(params, cfg: ModelConfig, ctx: ParallelCtx, h, labels,
 
 
 def lm_head_argmax(params, ctx: ParallelCtx, h):
-    """Greedy next-token over the vocab-parallel head. h: (B, D) -> (B,)."""
-    head = squeeze_tp(params["lm_head"], 1)
+    """Greedy next-token over the vocab-parallel head. h: (B, D) -> (B,).
+    (``logits_scaling`` divides every logit alike and moves no argmax.)"""
+    head = _head(params)
     v_l = head.shape[1]
     logits = jnp.einsum("bd,dv->bv", h, head.astype(h.dtype)).astype(jnp.float32)
     local_best = jnp.max(logits, axis=-1)
@@ -203,27 +242,55 @@ def lm_head_argmax(params, ctx: ParallelCtx, h):
 # ---------------------------------------------------------------------------
 
 
+def _residual(cfg: ModelConfig, x, y):
+    """x + y, the sublayer's output scaled by ``residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
+def _norm(cfg: ModelConfig, x, w):
+    return rms_norm(x, w, cfg.norm_eps)
+
+
+def _mlp_sublayer(p, cfg: ModelConfig, layer: LayerSpec, ctx: ParallelCtx, x,
+                  *, decode: bool = False):
+    """norm2, the layer's MLP (or experts) and its residual: (x, aux or None).
+    The decode step (one token) takes no sequence-parallel gather."""
+    h = _norm(cfg, x, p["norm2"])
+    if not decode:
+        h = ctx.sp_gather(h)
+    if layer.kind != "shared_attn" and cfg.moe is not None:
+        with jax.named_scope("fed.layer.moe"):
+            y, aux = moe.forward(p["moe"], cfg.moe, ctx, h, decode=decode)
+            return _residual(cfg, x, y), aux
+    with jax.named_scope("fed.layer.mlp"):
+        return _residual(cfg, x, mlp.forward(p["mlp"], cfg.mlp_kind, ctx, h)), None
+
+
+def _has_mlp(cfg: ModelConfig, layer: LayerSpec) -> bool:
+    return layer.kind != "ssm" or cfg.ssm_mlp
+
+
 def _block_apply(layer_params, shared_params, cfg: ModelConfig, layer: LayerSpec,
                  ctx: ParallelCtx, x, positions):
     """With sequence parallelism the residual x is (B, S/tp, D): norms act
     per-token on the shard, sublayers all-gather on entry (sp_gather) and
     reduce-scatter on exit (sp_scatter, inside each sublayer)."""
-    if layer.kind == "ssm":
-        h = ctx.sp_gather(rms_norm(x, layer_params["norm1"]))
-        return x + ssm.forward(layer_params["ssm"], cfg.ssm, ctx, h), None
     p = shared_params if layer.kind == "shared_attn" else layer_params
-    spec = cfg.attn_spec(layer)
-    h = ctx.sp_gather(rms_norm(x, p["norm1"]))
-    x = x + attention.forward(p["attn"], spec, ctx, h, positions)
-    h = ctx.sp_gather(rms_norm(x, p["norm2"]))
-    aux = None
-    if layer.kind != "shared_attn" and cfg.moe is not None:
-        y, aux = moe.forward(layer_params["moe"], cfg.moe, ctx, h)
-    elif layer.kind == "shared_attn":
-        y = mlp.forward(p["mlp"], cfg.mlp_kind, ctx, h)
+    if layer.kind == "ssm":
+        with jax.named_scope("fed.layer.ssm"):
+            h = ctx.sp_gather(_norm(cfg, x, p["norm1"]))
+            x = _residual(cfg, x, ssm.forward(p["ssm"], cfg.ssm, ctx, h,
+                                              eps=cfg.norm_eps))
     else:
-        y = mlp.forward(layer_params["mlp"], cfg.mlp_kind, ctx, h)
-    return x + y, aux
+        with jax.named_scope("fed.layer.attn"):
+            h = ctx.sp_gather(_norm(cfg, x, p["norm1"]))
+            x = _residual(cfg, x, attention.forward(
+                p["attn"], cfg.attn_spec(layer), ctx, h, positions))
+    if not _has_mlp(cfg, layer):
+        return x, None
+    return _mlp_sublayer(p, cfg, layer, ctx, x)
 
 
 def forward_hidden(params, cfg: ModelConfig, ctx: ParallelCtx, tokens,
@@ -231,7 +298,8 @@ def forward_hidden(params, cfg: ModelConfig, ctx: ParallelCtx, tokens,
                    compute_dtype=jnp.float32):
     """tokens (B, S_t); prefix_embeds (B, P, D) or None -> hidden (B, S, D)
     with S = P + S_t. Also returns summed MoE aux dict."""
-    x = embed(params, cfg, ctx, tokens).astype(compute_dtype)
+    with jax.named_scope("fed.layer.head"):
+        x = embed(params, cfg, ctx, tokens).astype(compute_dtype)
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(compute_dtype), x], axis=1)
     B, S, _ = x.shape
@@ -251,7 +319,8 @@ def forward_hidden(params, cfg: ModelConfig, ctx: ParallelCtx, tokens,
         x, aux = fn(layer_params, params.get("shared"), x=x, positions=positions)
         if aux is not None:
             aux_losses.append(aux["moe_aux_loss"])
-    x = ctx.sp_gather(rms_norm(x, params["final_norm"]))
+    with jax.named_scope("fed.layer.head"):
+        x = ctx.sp_gather(_norm(cfg, x, params["final_norm"]))
     moe_aux = sum(aux_losses) if aux_losses else jnp.float32(0.0)
     return x, {"moe_aux_loss": moe_aux}
 
@@ -333,35 +402,27 @@ def decode_step(params, caches, cfg: ModelConfig, ctx: ParallelCtx, tokens, pos,
     x = embed(params, cfg, ctx, tokens).astype(compute_dtype)
     new_caches = []
     for layer_params, layer, cache in zip(params["layers"], cfg.layers, caches):
-        if layer.kind == "ssm":
-            h = rms_norm(x, layer_params["norm1"])
-            y, new_c = ssm.decode(layer_params["ssm"], cfg.ssm, ctx, h, cache)
-            x = x + y
-            new_caches.append(new_c)
-            continue
         p = params.get("shared") if layer.kind == "shared_attn" else layer_params
-        spec = cfg.attn_spec(layer)
-        S_c = cache["k"].shape[3]
-        h = rms_norm(x, p["norm1"])
-        if layer.window is not None and S_c <= layer.window:
-            # ring-buffer window cache: write at pos % window
-            y, new_c = _decode_ring(p["attn"], spec, ctx, h, cache, pos, S_c)
+        h = _norm(cfg, x, p["norm1"])
+        if layer.kind == "ssm":
+            y, new_c = ssm.decode(p["ssm"], cfg.ssm, ctx, h, cache,
+                                  eps=cfg.norm_eps)
         else:
-            y, new_c = attention.decode(
-                p["attn"], spec, ctx, h, cache, pos,
-                seq_sharded=seq_sharded and layer.window is None,
-            )
-        x = x + y
-        h = rms_norm(x, p["norm2"])
-        if layer.kind != "shared_attn" and cfg.moe is not None:
-            y, _ = moe.forward(layer_params["moe"], cfg.moe, ctx, h, decode=True)
-        elif layer.kind == "shared_attn":
-            y = mlp.forward(p["mlp"], cfg.mlp_kind, ctx, h)
-        else:
-            y = mlp.forward(layer_params["mlp"], cfg.mlp_kind, ctx, h)
-        x = x + y
+            spec = cfg.attn_spec(layer)
+            S_c = cache["k"].shape[3]
+            if layer.window is not None and S_c <= layer.window:
+                # ring-buffer window cache: write at pos % window
+                y, new_c = _decode_ring(p["attn"], spec, ctx, h, cache, pos, S_c)
+            else:
+                y, new_c = attention.decode(
+                    p["attn"], spec, ctx, h, cache, pos,
+                    seq_sharded=seq_sharded and layer.window is None,
+                )
+        x = _residual(cfg, x, y)
+        if _has_mlp(cfg, layer):
+            x, _ = _mlp_sublayer(p, cfg, layer, ctx, x, decode=True)
         new_caches.append(new_c)
-    x = rms_norm(x, params["final_norm"])
+    x = _norm(cfg, x, params["final_norm"])
     nxt = lm_head_argmax(params, ctx, x[:, 0])
     return nxt, tuple(new_caches)
 
@@ -423,45 +484,37 @@ def prefill(params, cfg: ModelConfig, ctx: ParallelCtx, tokens, shape: InputShap
     x = ctx.sp_slice(x)
     caches = []
     for layer_params, layer in zip(params["layers"], cfg.layers):
+        p = params.get("shared") if layer.kind == "shared_attn" else layer_params
+        h = ctx.sp_gather(_norm(cfg, x, p["norm1"]))
         if layer.kind == "ssm":
-            h = ctx.sp_gather(rms_norm(x, layer_params["norm1"]))
             y, state = ssm.forward(
-                layer_params["ssm"], cfg.ssm, ctx, h, return_state=True
+                p["ssm"], cfg.ssm, ctx, h, eps=cfg.norm_eps, return_state=True
             )
-            x = x + y
-            caches.append(jax.tree_util.tree_map(
+            cache = jax.tree_util.tree_map(
                 lambda t: t.astype(jnp.float32) if t.ndim == 5 else t.astype(compute_dtype),
                 state,
-            ))
-            continue
-        p = params.get("shared") if layer.kind == "shared_attn" else layer_params
-        spec = cfg.attn_spec(layer)
-        S_c = shape.seq_len if layer.window is None else min(shape.seq_len, layer.window)
-        h = ctx.sp_gather(rms_norm(x, p["norm1"]))
-        y, cache = attention.prefill_kv(
-            p["attn"], spec, ctx, h, positions, max_len=max(S_c, S)
-        )
-        if layer.window is not None and S_c < max(S_c, S):
-            # Re-lay the last S_c keys into ring order (slot = pos % S_c),
-            # matching the _decode_ring invariant.
-            idx = [0] * S_c
-            for pos_abs in range(S - S_c, S):
-                idx[pos_abs % S_c] = pos_abs
-            idx = jnp.asarray(idx, jnp.int32)
-            cache = {
-                "k": jnp.take(cache["k"], idx, axis=3),
-                "v": jnp.take(cache["v"], idx, axis=3),
-            }
-        x = x + y
-        h = ctx.sp_gather(rms_norm(x, p["norm2"]))
-        if layer.kind != "shared_attn" and cfg.moe is not None:
-            y, _ = moe.forward(layer_params["moe"], cfg.moe, ctx, h)
-        elif layer.kind == "shared_attn":
-            y = mlp.forward(p["mlp"], cfg.mlp_kind, ctx, h)
+            )
         else:
-            y = mlp.forward(layer_params["mlp"], cfg.mlp_kind, ctx, h)
-        x = x + y
+            spec = cfg.attn_spec(layer)
+            S_c = shape.seq_len if layer.window is None else min(shape.seq_len, layer.window)
+            y, cache = attention.prefill_kv(
+                p["attn"], spec, ctx, h, positions, max_len=max(S_c, S)
+            )
+            if layer.window is not None and S_c < max(S_c, S):
+                # Re-lay the last S_c keys into ring order (slot = pos % S_c),
+                # matching the _decode_ring invariant.
+                idx = [0] * S_c
+                for pos_abs in range(S - S_c, S):
+                    idx[pos_abs % S_c] = pos_abs
+                idx = jnp.asarray(idx, jnp.int32)
+                cache = {
+                    "k": jnp.take(cache["k"], idx, axis=3),
+                    "v": jnp.take(cache["v"], idx, axis=3),
+                }
+        x = _residual(cfg, x, y)
+        if _has_mlp(cfg, layer):
+            x, _ = _mlp_sublayer(p, cfg, layer, ctx, x)
         caches.append(cache)
-    x = ctx.sp_gather(rms_norm(x, params["final_norm"]))
+    x = ctx.sp_gather(_norm(cfg, x, params["final_norm"]))
     nxt = lm_head_argmax(params, ctx, x[:, -1])
     return nxt, tuple(caches)

@@ -35,6 +35,7 @@ class SSMSpec:
     chunk: int = 128
     dt_min: float = 0.001
     dt_max: float = 0.1
+    conv_bias: bool = False  # the depthwise convolutions add a bias
 
     @property
     def d_inner(self) -> int:
@@ -61,7 +62,7 @@ def init_params(key, spec: SSMSpec, tp: int, dtype=jnp.float32):
         + jnp.log(spec.dt_min)
     )
     dt_bias = dt + jnp.log(-jnp.expm1(-dt))  # inverse softplus
-    return {
+    p = {
         # z (gate) and x streams, head-sharded
         "w_zx": dense_init(ks[0], (D, tp, 2 * di_l), dtype=dtype),
         # shared B and C projections (ngroups=1): replicated
@@ -75,6 +76,10 @@ def init_params(key, spec: SSMSpec, tp: int, dtype=jnp.float32):
         "norm": jnp.zeros((tp, di_l), dtype),
         "w_out": dense_init(ks[6], (tp, di_l, D), in_axis=1, dtype=dtype),
     }
+    if spec.conv_bias:
+        p["conv_x_bias"] = jnp.zeros((tp, di_l), dtype)
+        p["conv_bc_bias"] = jnp.zeros((2 * N,), dtype)
+    return p
 
 
 def param_meta(spec: SSMSpec, tp: int, dtype=jnp.float32):
@@ -83,7 +88,7 @@ def param_meta(spec: SSMSpec, tp: int, dtype=jnp.float32):
     h_l = spec.heads_local(tp)
     di_l = h_l * spec.head_dim
     D, N, W = spec.d_model, spec.state_dim, spec.conv_width
-    return {
+    m = {
         "w_zx": Meta((D, tp, 2 * di_l), dtype, P(None, "model", None), 1),
         "w_bc": Meta((D, 2 * N), dtype, P(None, None), tp),
         "w_dt": Meta((D, tp, h_l), dtype, P(None, "model", None), 1),
@@ -95,6 +100,10 @@ def param_meta(spec: SSMSpec, tp: int, dtype=jnp.float32):
         "norm": Meta((tp, di_l), dtype, P("model", None), 1),
         "w_out": Meta((tp, di_l, D), dtype, P("model", None, None), 1),
     }
+    if spec.conv_bias:
+        m["conv_x_bias"] = Meta((tp, di_l), dtype, P("model", None), 1)
+        m["conv_bc_bias"] = Meta((2 * N,), dtype, P(None), tp)
+    return m
 
 
 def _gated_rms_norm(y, z, w, ctx: ParallelCtx, eps: float = 1e-6):
@@ -108,15 +117,26 @@ def _gated_rms_norm(y, z, w, ctx: ParallelCtx, eps: float = 1e-6):
     return ((x * jax.lax.rsqrt(var + eps)) * (1.0 + w.astype(jnp.float32))).astype(y.dtype)
 
 
-def _depthwise_causal_conv(x, w):
-    """x: (B, S, C); w: (W, C) depthwise causal conv + silu."""
+def _depthwise_causal_conv(x, w, b=None):
+    """x: (B, S, C); w: (W, C); b: (C,) or None. Depthwise causal conv
+    (+ bias) + silu."""
     W = w.shape[0]
     xp = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
     out = sum(
         jax.lax.dynamic_slice_in_dim(xp, i, x.shape[1], axis=1) * w[i]
         for i in range(W)
     )
+    if b is not None:
+        out = out + b
     return jax.nn.silu(out)
+
+
+def _conv_biases(params, dtype):
+    """The x and B/C streams' conv biases, or ``(None, None)``."""
+    if "conv_x_bias" not in params:
+        return None, None
+    return (squeeze_tp(params["conv_x_bias"], 0).astype(dtype),
+            params["conv_bc_bias"].astype(dtype))
 
 
 def _project(params, spec: SSMSpec, tp_ctx: ParallelCtx, x):
@@ -131,8 +151,10 @@ def _project(params, spec: SSMSpec, tp_ctx: ParallelCtx, x):
     return z, xs, bc, dt
 
 
-def forward(params, spec: SSMSpec, ctx: ParallelCtx, x, *, return_state: bool = False):
-    """Training path (chunked SSD). x: (B, S, D) -> (B, S, D).
+def forward(params, spec: SSMSpec, ctx: ParallelCtx, x, *, eps: float = 1e-6,
+            return_state: bool = False):
+    """Training path (chunked SSD). x: (B, S, D) -> (B, S, D); ``eps``: the
+    gated RMSNorm's.
 
     return_state: also return the decode-ready state dict (final recurrent
     state + raw conv tails) so prefill can hand off to ``decode``.
@@ -146,8 +168,9 @@ def forward(params, spec: SSMSpec, ctx: ParallelCtx, x, *, return_state: bool = 
     h_l = dt.shape[-1]
     xs_raw, bc_raw = xs, bc  # pre-conv streams (decode conv state)
 
-    xs = _depthwise_causal_conv(xs, squeeze_tp(params["conv_x"], 0).astype(x.dtype))
-    bc = _depthwise_causal_conv(bc, params["conv_bc"].astype(x.dtype))
+    b_x, b_bc = _conv_biases(params, x.dtype)
+    xs = _depthwise_causal_conv(xs, squeeze_tp(params["conv_x"], 0).astype(x.dtype), b_x)
+    bc = _depthwise_causal_conv(bc, params["conv_bc"].astype(x.dtype), b_bc)
     Bm, Cm = bc[..., :N], bc[..., N:]
 
     A = -jnp.exp(squeeze_tp(params["A_log"], 0))  # (h_l,) negative
@@ -200,7 +223,7 @@ def forward(params, spec: SSMSpec, ctx: ParallelCtx, x, *, return_state: bool = 
     ).astype(jnp.float32)
     y = y.reshape(B, S, h_l * P_).astype(x.dtype)
     # gated RMSNorm (mamba2): norm(y * silu(z))
-    y = _gated_rms_norm(y, z, squeeze_tp(params["norm"], 0), ctx)
+    y = _gated_rms_norm(y, z, squeeze_tp(params["norm"], 0), ctx, eps)
     out = jnp.einsum("bsc,cd->bsd", y, squeeze_tp(params["w_out"], 0).astype(y.dtype))
     out = ctx.sp_scatter(out)
     if not return_state:
@@ -223,7 +246,8 @@ def init_state_shape(spec: SSMSpec, tp: int, batch: int):
     }
 
 
-def decode(params, spec: SSMSpec, ctx: ParallelCtx, x, state):
+def decode(params, spec: SSMSpec, ctx: ParallelCtx, x, state, *,
+           eps: float = 1e-6):
     """One recurrent decode step. x: (B, 1, D); state per init_state_shape."""
     B = x.shape[0]
     N, P_ = spec.state_dim, spec.head_dim
@@ -234,9 +258,14 @@ def decode(params, spec: SSMSpec, ctx: ParallelCtx, x, state):
     conv_x_buf = squeeze_tp(state["conv_x"], 1)  # (B, W-1, di_l)
     xs_hist = jnp.concatenate([conv_x_buf, xs], axis=1)  # (B, W, di_l)
     w_cx = squeeze_tp(params["conv_x"], 0).astype(x.dtype)
-    xs_t = jax.nn.silu(jnp.einsum("bwc,wc->bc", xs_hist, w_cx))[:, None]
+    xs_t = jnp.einsum("bwc,wc->bc", xs_hist, w_cx)
     bc_hist = jnp.concatenate([state["conv_bc"], bc], axis=1)
-    bc_t = jax.nn.silu(jnp.einsum("bwc,wc->bc", bc_hist, params["conv_bc"].astype(x.dtype)))[:, None]
+    bc_t = jnp.einsum("bwc,wc->bc", bc_hist, params["conv_bc"].astype(x.dtype))
+    b_x, b_bc = _conv_biases(params, x.dtype)
+    if b_x is not None:
+        xs_t, bc_t = xs_t + b_x, bc_t + b_bc
+    xs_t = jax.nn.silu(xs_t)[:, None]
+    bc_t = jax.nn.silu(bc_t)[:, None]
     Bm, Cm = bc_t[..., :N], bc_t[..., N:]
 
     A = -jnp.exp(squeeze_tp(params["A_log"], 0))
@@ -250,7 +279,7 @@ def decode(params, spec: SSMSpec, ctx: ParallelCtx, x, state):
     y = jnp.einsum("bn,bhpn->bhp", Cm[:, 0].astype(jnp.float32), h_new)
     y = y + squeeze_tp(params["D_skip"], 0)[None, :, None] * xh
     y = y.reshape(B, 1, h_l * P_).astype(x.dtype)
-    y = _gated_rms_norm(y, z, squeeze_tp(params["norm"], 0), ctx)
+    y = _gated_rms_norm(y, z, squeeze_tp(params["norm"], 0), ctx, eps)
     out = jnp.einsum("bsc,cd->bsd", y, squeeze_tp(params["w_out"], 0).astype(y.dtype))
     out = ctx.psum_model(out)
     new_state = {

@@ -106,7 +106,8 @@ class TestDecodeConsistency:
     """Teacher-forced forward and incremental decode agree on next tokens."""
 
     @pytest.mark.parametrize("arch", ["gemma3-4b", "h2o-danube-3-4b",
-                                      "mamba2-370m", "chatglm3-6b"])
+                                      "mamba2-370m", "chatglm3-6b",
+                                      "granite-4.0-h-micro"])
     def test_prefill_matches_forward(self, arch):
         cfg = get_config(arch, reduced=True)
         params = model_lib.init_params(jax.random.key(0), cfg, tp=1)
@@ -132,6 +133,7 @@ class TestDecodeConsistency:
         "gemma3-4b",
         "mamba2-370m",
         "zamba2-1.2b",
+        "granite-4.0-h-micro",
     ])
     def test_decode_matches_forward(self, arch):
         """Decode one token, compare against teacher-forced forward on the

@@ -17,10 +17,11 @@ ASSIGNMENT = {
     "qwen3-moe-30b-a3b": (48, 2048, 32, 4, 768, 151936, "moe"),
     "pixtral-12b": (40, 5120, 32, 8, 14336, 131072, "vlm"),
     "chatglm3-6b": (28, 4096, 32, 2, 13696, 65024, "dense"),
+    "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352, "hybrid"),
 }
 
 MOE = {"phi3.5-moe-42b-a6.6b": (16, 2), "qwen3-moe-30b-a3b": (128, 8)}
-SSM_STATE = {"zamba2-1.2b": 64, "mamba2-370m": 128}
+SSM_STATE = {"zamba2-1.2b": 64, "mamba2-370m": 128, "granite-4.0-h-micro": 128}
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -78,6 +79,22 @@ def test_zamba_shared_pattern():
     shared = [i for i, l in enumerate(cfg.layers) if l.kind == "shared_attn"]
     assert shared == [5, 11, 17, 23, 29, 35]
     assert cfg.shared_attn
+
+
+def test_granite_hybrid_pattern():
+    """config.json's layer_types, NoPE, the muP multipliers, the tied head."""
+    cfg = get_config("granite-4.0-h-micro")
+    attn = [i for i, l in enumerate(cfg.layers) if l.kind == "attn"]
+    assert attn == [5, 15, 25, 35]
+    assert cfg.ssm_mlp and cfg.tie_embeddings and cfg.rotary_frac == 0.0
+    assert (cfg.embedding_multiplier, cfg.residual_multiplier,
+            cfg.logits_scaling) == (12.0, 0.22, 8.0)
+    assert cfg.attn_spec(cfg.layers[5]).scale == 0.015625
+    assert cfg.norm_eps == 1e-5
+    s = cfg.ssm
+    assert (s.num_heads, s.head_dim, s.d_inner, s.conv_width, s.chunk) == (
+        64, 64, 4096, 4, 256)
+    assert s.conv_bias
 
 
 def test_vocab_padding():

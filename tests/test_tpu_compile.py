@@ -10,6 +10,8 @@ that is described, not attached, at the widths the lanes run:
   * the federated LM lane: 8 clients x dim 1,096,032;
   * the benchmark's mamba2-370m cohort: 8 clients x dim 129,385,856
     (the row-native batch encode);
+  * the benchmark's granite-4.0-h-micro cohort: 2 silos x dim 315,062,592
+    (the row-native batch encode at two rows);
   * the packed wire kernels at a lane-aligned word count (10-bit fields,
     74,112 words — the EMNIST word count rounded up to whole lanes).
 
@@ -43,6 +45,7 @@ from repro.kernels.rqm_kernel import LANE, pick_block_rows, rqm_quantize_2d
 EMNIST = (40, 222_030)     # paper cohort x EMNIST CNN dim
 LM = (8, 1_096_032)        # lm lane cohort x its model dim
 MAMBA2 = (8, 129_385_856)  # the benchmark's mamba2-370m cohort x dim
+GRANITE = (2, 315_062_592)  # the benchmark's granite-4.0-h-micro cohort x dim
 BITS = 10                  # wire width of a 40-client RQM m=16 sum
 ALIGNED_DIM = 3 * 74_112 - 2    # -> 74,112 packed words = 579 lanes
 ALIGNED8_DIM = 3 * 73_728       # -> 576 lanes: 8-row unpack blocks
@@ -114,8 +117,8 @@ def test_encode_kernel(spec, name):
              spec((), jnp.uint32), kernel=f"{name}_encode")
 
 
-@pytest.mark.parametrize("shape", [EMNIST, LM, MAMBA2],
-                         ids=["emnist", "lm", "mamba2"])
+@pytest.mark.parametrize("shape", [EMNIST, LM, MAMBA2, GRANITE],
+                         ids=["emnist", "lm", "mamba2", "granite"])
 @pytest.mark.parametrize("name", sorted(ENCODERS))
 def test_encode_rows_kernel(spec, name, shape):
     """The materialized batch encode as the engines run it on the chip:
