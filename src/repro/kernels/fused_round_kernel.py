@@ -69,17 +69,29 @@ ENCODERS = {
 
 
 ROWS_BLOCK_ELEMS = 16 * 1024  # elements per row-native encode block
+FOLD_WIDTH = SUBLANE * LANE  # one row viewed as (n, 128) fills whole vregs
 
 
 def pick_rows_block(rows: int, dim: int,
                     elems: int = ROWS_BLOCK_ELEMS) -> tuple[int, int]:
     """``(block_r, block_c)`` of the row-native encode for a ``(rows, dim)``
     batch: at most one sublane of rows (a shorter batch takes its own
-    height), and lanes in multiples of 128 up to about ``elems`` elements a
-    block (the whole width where ``dim`` is narrower than that)."""
+    height), and about ``elems`` elements a block (the whole width where
+    ``dim`` is narrower than that). Lanes come in multiples of 128; with
+    fewer rows than a sublane, in multiples of ``FOLD_WIDTH`` (never
+    fewer than one), so that each row of the block folds into whole
+    ``(8, 128)`` vregs (``rows_block_folds``)."""
     block_r = min(rows, SUBLANE)
-    block_c = max(LANE, elems // block_r // LANE * LANE)
+    unit = FOLD_WIDTH if block_r < SUBLANE else LANE
+    block_c = max(unit, elems // block_r // unit * unit)
     return block_r, (dim if block_c >= dim else block_c)
+
+
+def rows_block_folds(block_r: int, block_c: int) -> bool:
+    """True when the row-native encode folds each row of a
+    ``(block_r, block_c)`` block into a ``(block_c // 128, 128)`` value:
+    fewer rows than a sublane, and a width of whole ``FOLD_WIDTH`` rows."""
+    return block_r < SUBLANE and block_c % FOLD_WIDTH == 0
 
 
 def _encode_rows_kernel(seed_ref, off_ref, x_ref, z_ref, *, encode, params,
@@ -91,6 +103,24 @@ def _encode_rows_kernel(seed_ref, off_ref, x_ref, z_ref, *, encode, params,
     g_col = pl.program_id(1).astype(jnp.uint32) * jnp.uint32(block_c) + c_ids
     z_ref[...] = encode(x_ref[...], seed_ref[0, 0],
                         g_row * jnp.uint32(dim) + g_col, params)
+
+
+def _encode_rows_folded_kernel(seed_ref, off_ref, x_ref, z_ref, *, encode,
+                               params, dim: int, block_r: int, block_c: int):
+    """``_encode_rows_kernel`` with each row of the block encoded as a
+    ``(block_c // 128, 128)`` value: the same counters, whole vregs."""
+    shape = (block_c // LANE, LANE)
+    g_row0 = (off_ref[0, 0] + pl.program_id(0).astype(jnp.uint32)
+              * jnp.uint32(block_r))
+    g_col = (pl.program_id(1).astype(jnp.uint32) * jnp.uint32(block_c)
+             + jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+             * jnp.uint32(LANE)
+             + jax.lax.broadcasted_iota(jnp.uint32, shape, 1))
+    for r in range(block_r):
+        counter = (g_row0 + jnp.uint32(r)) * jnp.uint32(dim) + g_col
+        z = encode(x_ref[r:r + 1, :].reshape(shape), seed_ref[0, 0], counter,
+                   params)
+        z_ref[r:r + 1, :] = z.reshape(1, block_c)
 
 
 def encode_rows_2d(x, seed, row_offset, encode_name: str, params, *,
@@ -116,12 +146,26 @@ def encode_rows_2d(x, seed, row_offset, encode_name: str, params, *,
     16 MiB scoped limit. Smaller blocks ran faster on a v5e (RQM on the
     8 x 129,385,856 stack: 69.5 ms at 16K elements, 82.7 ms at 32K,
     110.5 ms at 64K).
+
+    Fewer rows than a sublane fold (``rows_block_folds``): such a stack
+    sits in HBM tiled ``T(rows, 128)``, and a ``(2, 8192)`` value laid
+    out in ``(8, 128)`` vregs fills 2 of 8 sublanes, behind relayouts
+    (284 ms for RQM on the 2 x 315,062,592 stack on a v5e, 6.8x the
+    8-row rate a coordinate). The folded kernel, named
+    ``<name>_encode_rows_folded``, loops over the block's rows and
+    encodes each as a ``(block_c // 128, 128)`` value that fills whole
+    vregs, with the same counters. The block keeps the 16K-element
+    budget in whole 1,024-lane rows (2 rows: 2 x 8192; 3: 3 x 5120), so
+    its I/O buffers take the same 256 KiB and one row's encode about
+    256 KiB live.
     """
     rows, dim = x.shape
     block_r, block_c = pick_rows_block(rows, dim, block_elems)
     grid = (pl.cdiv(rows, block_r), pl.cdiv(dim, block_c))
+    folds = rows_block_folds(block_r, block_c)
+    kernel = _encode_rows_folded_kernel if folds else _encode_rows_kernel
     return pl.pallas_call(
-        functools.partial(_encode_rows_kernel, encode=ENCODERS[encode_name],
+        functools.partial(kernel, encode=ENCODERS[encode_name],
                           params=params, dim=dim, block_r=block_r,
                           block_c=block_c),
         grid=grid,
@@ -133,7 +177,7 @@ def encode_rows_2d(x, seed, row_offset, encode_name: str, params, *,
         out_specs=pl.BlockSpec((block_r, block_c), lambda r, c: (r, c)),
         out_shape=jax.ShapeDtypeStruct((rows, dim), jnp.int32),
         interpret=interpret,
-        name=f"{encode_name}_encode_rows",
+        name=f"{encode_name}_encode_rows{'_folded' if folds else ''}",
     )(seed.reshape(1, 1), row_offset.reshape(1, 1), x)
 
 

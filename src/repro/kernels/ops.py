@@ -27,7 +27,8 @@ Every mechanism kernel gets the same three entry points, built once by
     and the output inherits the kernel<->mechanism parity contract on the
     flattened input (see kernels/ref.py). On a TPU it runs the row-native
     kernel (``fused_round_kernel.encode_rows_2d``, named
-    ``<name>_encode_rows``) over the batch in its own layout, so the
+    ``<name>_encode_rows``, or ``<name>_encode_rows_folded`` on fewer
+    rows than a sublane) over the batch in its own layout, so the
     stack is never flattened, padded or relaid; elsewhere ``_fast``'s jnp
     twin on the flattened batch — the same integers.
   * ``<name>_round_sum(x, key, params, weights=..., row_offset=...,

@@ -7,10 +7,12 @@ encodes element ``i`` at counter ``offset + i``. With the element offset
 ``row_offset * dim`` both draw the same counters, so every mechanism must
 emit the same integers, bit for bit: on sublane-aligned and unaligned row
 counts, widths that are and are not whole lanes, the default block and a
-small one whose last row and column blocks are edge blocks, with no row
-offset and with a traced one (the shard engine's slice position). Both
-kernels run in interpret mode here; ``tests/test_tpu_compile.py`` compiles
-the row-native kernel for a described chip.
+small one whose last row and column blocks are edge blocks, with fewer
+rows than a sublane both whole-width and folded (each row encoded as
+whole ``(8, 128)`` vregs), with no row offset and with a traced one (the
+shard engine's slice position). Both kernels run in interpret mode here;
+``tests/test_tpu_compile.py`` compiles the row-native kernel for a
+described chip.
 """
 import jax
 import jax.numpy as jnp
@@ -21,7 +23,8 @@ from repro.core.grid import RQMParams
 from repro.core.pbm import PBMParams
 from repro.core.qmgeo import QMGeoParams
 from repro.kernels import ops
-from repro.kernels.fused_round_kernel import encode_rows_2d, pick_rows_block
+from repro.kernels.fused_round_kernel import (encode_rows_2d, pick_rows_block,
+                                              rows_block_folds)
 
 PARAMS = {
     "rqm": RQMParams(c=1.0, delta=1.0, m=16, q=0.42),
@@ -32,8 +35,11 @@ FLAT_OPS = {"rqm": ops.rqm, "pbm": ops.pbm, "qmgeo": ops.qmgeo}
 BATCH_OPS = {"rqm": ops.rqm_batch, "pbm": ops.pbm_batch,
              "qmgeo": ops.qmgeo_batch}
 # (8, 4096): whole sublanes and lanes; (40, 1000): dim not a multiple of
-# 128; (3, 300): fewer rows than a sublane; (10, 128): a partial row block
-SHAPES = [(8, 4096), (40, 1000), (3, 300), (10, 128)]
+# 128; (3, 300): fewer rows than a sublane, whole-width and unfolded;
+# (10, 128): a partial row block; (1, 20000), (2, 20000), (3, 9000),
+# (7, 5000): fewer rows than a sublane, folded, with a column edge block
+SHAPES = [(8, 4096), (40, 1000), (3, 300), (10, 128),
+          (1, 20_000), (2, 20_000), (3, 9_000), (7, 5_000)]
 # the default block, and 1K elements: several column blocks, the last an
 # edge block wherever dim is not a multiple of the block width
 BLOCKS = {"default": None, "1k": 1024}
@@ -88,11 +94,27 @@ def test_batch_dispatch_interpret_equals_twin(name, monkeypatch):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# the blocks below that fold: fewer rows than a sublane, whole 1,024-lane rows
+FOLDED = {(2, 8192), (3, 5120), (1, 16_384), (2, 4096), (2, 1024), (3, 1024),
+          (7, 1024)}
+
+
 @pytest.mark.parametrize("shape,block", [
     ((8, 129_385_856), (8, 2048)),   # the LM stack: 8-row blocks
     ((40, 222_030), (8, 2048)),      # EMNIST: an edge column block
     ((3, 300), (3, 300)),            # narrower than a block: whole batch
     ((2, 100_000), (2, 8192)),       # fewer rows than a sublane
+    ((3, 100_000), (3, 5120)),       # whole 1,024-lane rows
+    ((1, 100_000), (1, 16_384)),
+    ((2, 315_062_592), (2, 8192)),   # the granite stack
+    ((2, 4096), (2, 4096)),          # whole width, whole vregs
+    ((2, 5000), (2, 5000)),          # whole width, not whole vregs
+    # the 1K-element test block: never below one 1,024-lane row
+    ((2, 20_000, 1024), (2, 1024)),
+    ((3, 9_000, 1024), (3, 1024)),
+    ((7, 5_000, 1024), (7, 1024)),
+    ((40, 1000, 1024), (8, 128)),
 ])
 def test_block_shape(shape, block):
     assert pick_rows_block(*shape) == block
+    assert rows_block_folds(*block) == (block in FOLDED)

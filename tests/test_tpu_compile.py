@@ -11,7 +11,8 @@ that is described, not attached, at the widths the lanes run:
   * the benchmark's mamba2-370m cohort: 8 clients x dim 129,385,856
     (the row-native batch encode);
   * the benchmark's granite-4.0-h-micro cohort: 2 silos x dim 315,062,592
-    (the row-native batch encode at two rows);
+    (the row-native batch encode at two rows, folded), and the same
+    encode of three and of one silo;
   * the packed wire kernels at a lane-aligned word count (10-bit fields,
     74,112 words — the EMNIST word count rounded up to whole lanes).
 
@@ -46,6 +47,8 @@ EMNIST = (40, 222_030)     # paper cohort x EMNIST CNN dim
 LM = (8, 1_096_032)        # lm lane cohort x its model dim
 MAMBA2 = (8, 129_385_856)  # the benchmark's mamba2-370m cohort x dim
 GRANITE = (2, 315_062_592)  # the benchmark's granite-4.0-h-micro cohort x dim
+SILO3 = (3, 129_385_856)    # three silos x the mamba2-370m dim
+SILO1 = (1, 315_062_592)    # one silo x the granite dim
 BITS = 10                  # wire width of a 40-client RQM m=16 sum
 ALIGNED_DIM = 3 * 74_112 - 2    # -> 74,112 packed words = 579 lanes
 ALIGNED8_DIM = 3 * 73_728       # -> 576 lanes: 8-row unpack blocks
@@ -117,14 +120,18 @@ def test_encode_kernel(spec, name):
              spec((), jnp.uint32), kernel=f"{name}_encode")
 
 
-@pytest.mark.parametrize("shape", [EMNIST, LM, MAMBA2, GRANITE],
-                         ids=["emnist", "lm", "mamba2", "granite"])
+@pytest.mark.parametrize("shape,kernel", [
+    (EMNIST, "encode_rows"), (LM, "encode_rows"), (MAMBA2, "encode_rows"),
+    (GRANITE, "encode_rows_folded"), (SILO3, "encode_rows_folded"),
+    (SILO1, "encode_rows_folded"),
+], ids=["emnist", "lm", "mamba2", "granite", "silo3", "silo1"])
 @pytest.mark.parametrize("name", sorted(ENCODERS))
-def test_encode_rows_kernel(spec, name, shape):
+def test_encode_rows_kernel(spec, name, shape, kernel):
     """The materialized batch encode as the engines run it on the chip:
     the (clients, dim) stack in its own layout, an edge column block
     where dim is not a multiple of the block width, and a traced row
-    offset (a shard's slice of a larger cohort)."""
+    offset (a shard's slice of a larger cohort). Fewer rows than a
+    sublane run the folded kernel, each row encoded as whole vregs."""
     params = ENCODERS[name][1]
 
     def encode(x, seed, row_offset):
@@ -132,7 +139,7 @@ def test_encode_rows_kernel(spec, name, shape):
                                                  params)
 
     _compile(encode, spec(shape, jnp.float32), spec((), jnp.uint32),
-             spec((), jnp.uint32), kernel=f"{name}_encode_rows")
+             spec((), jnp.uint32), kernel=f"{name}_{kernel}")
 
 
 @pytest.mark.parametrize("shape", [EMNIST, LM], ids=["emnist", "lm"])
