@@ -187,19 +187,16 @@ class AsyncEngine(Engine):
 
     def _make_async_round_step(self):
         """The buffered-aggregation round step: per-row stale parameter
-        gather -> clipped gradient -> fused/materialized integer encode ->
-        {0,1}-masked SecAgg sum -> decode at the realized count -> scalar
-        staleness discount -> server-optimizer apply -> ring shift."""
+        gather -> clipped gradient -> the shared ``rounds.encode_sum``
+        over the {0,1} delivered mask -> ``rounds.decode_apply`` at the
+        realized count, with the scalar staleness discount between
+        decode and apply -> ring shift."""
         tr, cfg = self.tr, self.tr.cfg
         mech, opt, slate = tr.mech, tr.server_opt, tr.slate
         client_grad = tr._client_grad
         S = self.max_staleness
         streamed = self._streamed
         discounted = self._discounted
-        fused = cfg.fused_rounds
-        # timeout-straggled aggregations can realize empty: guard the
-        # apply exactly like the heterogeneous engines do
-        apply = rounds.make_server_apply(opt, cfg, hetero=True)
 
         def round_step(hist, opt_state, key, data, stale,
                        delivered, discount=None):
@@ -218,26 +215,20 @@ class AsyncEngine(Engine):
                 # fetched: gather per-row parameters from the ring
                 grads = rounds.cohort_grads(client_grad, hist[stale], batch,
                                             per_client_params=True)
-            part = delivered
-            if fused:
-                z_sum = mech.quantize_sum_batch(grads, k_enc, weights=part)
-            else:
-                z = mech.quantize_batch(grads, k_enc)
-                z = z * part.astype(z.dtype)[:, None]  # stragglers: 0
-                z_sum = jnp.sum(z, axis=0, dtype=z.dtype)
-            n_real = jnp.sum(part, dtype=jnp.int32)
-            n_dec = jnp.maximum(n_real, 1)  # empty: releases nothing
-            g_hat = mech.decode_sum(z_sum, n_dec)
-            if discounted:
-                g_hat = g_hat * discount  # post-processing of the release
-            new, new_state = apply(hist[0], opt_state, g_hat, n_real)
+            # stragglers are masked out of the sum; a timed-out buffer can
+            # realize empty, and then releases nothing and moves nothing
+            z_sum, part = rounds.encode_sum(mech, cfg, grads, k_enc,
+                                            delivered)
+            new, new_state, n_real = rounds.decode_apply(
+                mech, cfg, opt, hist[0], opt_state, z_sum, part,
+                discount=discount if discounted else None,
+            )
             if S == 0:
                 new_hist = new[None, :]
             else:
                 new_hist = jnp.concatenate([new[None, :], hist[:-1]], axis=0)
-            new_hist, new_state = jax.lax.optimization_barrier(
-                (new_hist, new_state)
-            )
+            new_hist, new_state, z_sum = rounds.round_boundary(
+                new_hist, new_state, z_sum, cfg)
             return new_hist, new_state, key, z_sum, n_real
 
         return round_step

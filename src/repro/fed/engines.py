@@ -28,7 +28,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import wire
 from repro.data.federated import sample_clients
-from repro.distributed.step import MeshPlan
 from repro.fed import cohort, rounds, staging
 from repro.fed.engine import Engine, register_engine
 from repro.launch.mesh import make_fed_mesh, make_shard_mesh
@@ -253,9 +252,6 @@ class ShardEngine(Engine):
                     f"{tr.task.name!r} is single-shard only"
                 )
             tr._mesh = make_fed_mesh(self.shards, self.model_shards)
-            tr._plan = MeshPlan(mesh=tr._mesh, client_axes=("shard",),
-                                model_axis="model")
-            assert tr._plan.tp == self.model_shards
             # no client axes on the task ctx: a client's loss must stay
             # local to its shard (client_grad is mapped over the cohort
             # slice WITHIN a shard — cross-client collectives would sum
@@ -264,11 +260,8 @@ class ShardEngine(Engine):
                                        tp=self.model_shards)
             tr.task.bind_model_axis(tr._task_ctx, tr._mesh)
         else:
+            # pure client-parallel mesh: every shard a whole client group
             tr._mesh = make_shard_mesh(self.shards)
-            # pure client-parallel plan: every shard a whole client group
-            tr._plan = MeshPlan(mesh=tr._mesh, client_axes=("shard",),
-                                model_axis=None)
-            assert tr._plan.tp == 1 and tr._plan.n_clients == self.shards
 
     def build(self):
         tr = self.tr

@@ -1,4 +1,4 @@
-"""The jitted Algorithm-1 round-step and block builders.
+"""The jitted Algorithm-1 round, written once, and the block builder.
 
 One round, identical under every engine: split the round key; sample the
 cohort slate; per-client clipped gradient (or FedAvg delta) over the
@@ -9,30 +9,34 @@ decode-then-apply boundary (``repro.optim.Optimizer`` — plain SGD is the
 paper's w - lr*g_hat, bit-identical by construction; momentum/adam carry
 their state through the scan/shard carry, donated with the parameters).
 
-``FedConfig.fused_rounds`` collapses the encode / mask / sum triple into
-the mechanism's ``quantize_sum_batch`` (one streamed reduction, no
-materialized encoded batch — kernels/fused_round_kernel.py) and, for
-plain-SGD grid mechanisms, the decode / apply pair into
-``decode_apply_sum`` — both bit-identical to the unfused sequence (see
-``use_fused_apply`` and docs/kernels.md).
+The body is three stage functions that every jitted round calls:
+``encode_sum`` (mask, clip->encode, the integer sum and, on a mesh, the
+cross-shard exchange), ``decode_apply`` (decode, server step, empty-round
+guard) and ``round_boundary``. ``make_round_step`` strings them together
+for scan, perround and shard (``shards=S``); the async engine calls them
+around its stale-parameter gather, discount and ring shift. A change to
+the body therefore lands once, and every engine releases the same
+integers. ``FedConfig.fused_rounds`` collapses encode / mask / sum into
+the mechanism's ``quantize_sum_batch`` and, for plain-SGD grid
+mechanisms, decode / apply into ``decode_apply_sum`` — both bit-identical
+to the unfused sequence (``use_fused_apply``, docs/kernels.md).
 
-The trailing optimization_barrier pins the round boundary: XLA cannot
-fuse one round's float math into the next, so the body compiles to the
-same numerics whether it stands alone (perround) or is repeated inside an
-unrolled scan block — the bit-for-bit parity the engine tests assert on
-CPU. (Without it, cross-round fusion and while-loop single-threading on
-XLA:CPU shift gradients by ~1 ULP, which RQM's randomized rounding then
-amplifies.)
+``round_boundary``'s optimization_barrier pins the round boundary: XLA
+cannot fuse one round's float math into the next, so the body compiles
+to the same numerics whether it stands alone (perround) or is repeated
+inside an unrolled scan block — the bit-for-bit parity the engine tests
+assert on CPU. (Without it, cross-round fusion and while-loop
+single-threading on XLA:CPU shift gradients by ~1 ULP, which RQM's
+randomized rounding then amplifies.)
 
-Each stage of a round is a device scope (``jax.named_scope``), which the
-compiler keeps in the ``op_name`` of every op it emits, so a device trace
-splits the round by stage: ``fed.grads`` (the cohort's gradient map; its
-own ops stack the gradients), ``fed.client_grad`` (one client's gradient
-and clip), ``fed.encode_sum`` (encode and participation mask; with fused
-rounds also the sum), ``fed.secure_sum`` (the cohort sum and the
-cross-shard SecAgg exchange) and ``fed.decode_apply`` (decode and the
-server step). The key split, the slate draw and the gather of the
-cohort's data lie outside them.
+Each stage is a device scope (``jax.named_scope``), which the compiler
+keeps in the ``op_name`` of every op it emits, so a device trace splits
+the round by stage: ``fed.grads`` (the cohort's gradient map; its own
+ops stack the gradients), ``fed.client_grad`` (one client's gradient and
+clip), ``fed.encode_sum`` (mask and encode; with fused rounds also the
+sum), ``fed.secure_sum`` (the cohort sum and the cross-shard exchange)
+and ``fed.decode_apply`` (decode and the server step). The key split,
+the slate draw and the gather of the cohort's data lie outside them.
 
 Builders return traced-side callables; the engine classes
 (``repro.fed.engines``) own jit/shard_map wrapping and dispatch.
@@ -165,89 +169,154 @@ def cohort_grads(client_grad, params, batch, *, per_client_params=False):
         return jax.lax.map(lambda b: client_grad(params, b), batch)
 
 
-def make_server_apply(opt, cfg, hetero):
-    """The decode-then-apply boundary: g_hat -> (new_params, new_state)
-    via the pluggable server optimizer. Empty heterogeneous rounds (zero
-    surviving participants) release nothing and move NOTHING — neither
-    parameters nor optimizer state."""
-    lr = cfg.lr
+def encode_sum(mech, cfg, grads, k_enc, valid=None, k_drop=None, *,
+               pack_bits=None, shard=None, exchange=None):
+    """Stage one: the (rows, dim) clipped gradient stack -> the integer
+    SecAgg sum. Returns ``(z_sum, part)``.
 
-    def apply(flat, opt_state, g_hat, n_real):
-        new, new_state = opt.update(g_hat, opt_state, flat, lr)
-        if hetero:
+    ``valid``: the slate's validity mask when the round masks its cohort
+    (heterogeneous cohorts; the async engine's delivered rows), made the
+    participation mask ``part`` here; None: all participate. ``shard=(j,
+    total_rows)``: the stack is rows ``[j*n, (j+1)*n)`` of the slate —
+    the shard masks its own slice, and the row offset keeps the RNG
+    counters those of the whole batch. ``exchange``: the cross-shard sum
+    of the shard-local partial. Fused rounds encode and sum in ONE
+    streamed reduction (with ``pack_bits``, into b-bit wire words), so
+    without an exchange there is no ``fed.secure_sum`` scope."""
+    rows = {}
+    with jax.named_scope("fed.encode_sum"):
+        part = local = None
+        if valid is not None:
+            part = local = cohort.participation(cfg, valid, k_drop)
+        if shard is not None:
+            j, total_rows = shard
+            n = grads.shape[0]
+            if part is not None:
+                local = jax.lax.dynamic_slice_in_dim(part, j * n, n)
+            rows = dict(row_offset=j * n, total_rows=total_rows)
+        if cfg.fused_rounds:
+            z_sum = mech.quantize_sum_batch(grads, k_enc, weights=local,
+                                            **rows, pack_bits=pack_bits)
+        else:
+            z = mech.quantize_batch(grads, k_enc, **rows)
+            if local is not None:
+                z = z * local.astype(z.dtype)[:, None]  # non-participants
+    if cfg.fused_rounds and exchange is None:
+        return z_sum, part
+    with jax.named_scope("fed.secure_sum"):
+        if not cfg.fused_rounds:
+            z_sum = jnp.sum(z, axis=0, dtype=z.dtype)
+        if exchange is not None:
+            z_sum = exchange(z_sum)
+    return z_sum, part
+
+
+def decode_apply(mech, cfg, opt, flat, opt_state, z_sum, part, *,
+                 fused=False, pack_bits=None, discount=None):
+    """Stage two: decode the sum at the realized cohort size and take the
+    server step. Returns ``(new, new_state, n_real)``. With ``part`` (from
+    ``encode_sum``) an empty round releases nothing and moves NOTHING —
+    neither parameters nor optimizer state. ``fused``:
+    ``decode_apply_sum`` (``use_fused_apply``). ``discount``: a scalar on
+    the decoded aggregate (the async staleness weight, post-processing)."""
+    with jax.named_scope("fed.decode_apply"):
+        if part is None:
+            n_real = jnp.int32(cfg.clients_per_round)
+            n_dec = cfg.clients_per_round
+        else:
+            n_real = jnp.sum(part, dtype=jnp.int32)
+            n_dec = jnp.maximum(n_real, 1)
+        if fused:
+            new = decode_apply_sum(flat, z_sum, mech.params, n_dec, cfg.lr,
+                                   pack_bits=pack_bits)
+            new_state = opt_state
+        else:
+            g_hat = mech.decode_sum(z_sum, n_dec)
+            if discount is not None:
+                g_hat = g_hat * discount
+            new, new_state = opt.update(g_hat, opt_state, flat, cfg.lr)
+        if part is not None:
             ok = n_real > 0
             new = jnp.where(ok, new, flat)
             new_state = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(ok, a, b), new_state, opt_state
-            )
-        return new, new_state
-
-    return apply
+                lambda a, b: jnp.where(ok, a, b), new_state, opt_state)
+    return new, new_state, n_real
 
 
-def make_round_step(mech, cfg, opt, slate, client_grad):
-    """The device-resident round step shared verbatim by the "perround"
-    and "scan" engines (and, via the specialized 1-shard path, "shard").
-    Carry is (flat, opt_state, key); also returns the round's encoded
-    SecAgg sum and realized participant count for host-side accounting."""
+def round_boundary(new, new_state, z_sum, cfg, pack_bits=None):
+    """The barrier over the carried state; then, only when the run
+    collects its sums, the packed sum's exact unpack, so the collected
+    observable is always the DENSE sum (otherwise dead code)."""
+    new, new_state = jax.lax.optimization_barrier((new, new_state))
+    if pack_bits is not None and cfg.collect_sums:
+        z_sum = wire.unpack_bits(z_sum, pack_bits, new.shape[-1])
+    return new, new_state, z_sum
+
+
+def make_round_step(mech, cfg, opt, slate, client_grad, *, shards=None,
+                    streamed=False):
+    """The synchronous engines' round step: carry (flat, opt_state, key),
+    plus the round's SecAgg sum and realized count for accounting.
+
+    ``shards=None``: one program over the whole cohort (scan, perround,
+    async's plain corner). ``shards=S``: inside shard_map over
+    ('shard',). The replicated key gives every shard the same slate and
+    masks; each grads+encodes its slate/S slice, and one cross-shard sum
+    of integer levels — never floats — precedes the replicated decode
+    and step. On one shard the slice and row offset are specialized
+    away: the scan program plus the exchange. ``streamed``: the block
+    staging already gathered and sharded each round's slate."""
     hetero = cohort.is_hetero(cfg)
-    apply = make_server_apply(opt, cfg, hetero)
-    fused = cfg.fused_rounds
     fused_apply = use_fused_apply(mech, cfg)
     pack_bits = hot_path_pack_bits(mech, cfg, slate)
+    multi = shards is not None and shards > 1
+    n_per = slate // shards if multi else slate
+    exchange = None
+    if shards is not None:
+        bound = mech.sum_bound(slate)  # forced-packing safety checked at init
+        prefer_packed = cfg.shard_packed is None or cfg.shard_packed
+
+        def exchange(z_part):
+            # packed partials are wire words whose fields int32 addition
+            # sums independently (field-safe at the full-slate bound), so
+            # a plain psum IS the SecAgg sum; dense levels go through
+            # secure_sum_bounded (packed when the bound allows)
+            if pack_bits is not None:
+                return jax.lax.psum(z_part, "shard")
+            return secagg.secure_sum_bounded(z_part, ("shard",), bound,
+                                             packed=prefer_packed)
 
     def round_step(flat, opt_state, key, data):
         key, k_sample, k_enc, k_drop = cohort.split_round_keys(cfg, key)
-        ids, valid = cohort.sample_slate(cfg, slate, k_sample)
-        grads = cohort_grads(client_grad, flat, index_batch(data, ids))
-        # Shared clip->encode dispatch (clip is idempotent on the
-        # already-clipped grads): one fused kernel call over the whole
-        # (clients, dim) stack when the mechanism is kernel-backed. With
-        # fused_rounds the encode and the SecAgg sum are ONE streamed
-        # reduction — the (clients, dim) encoded batch never exists, and
-        # the round has no fed.secure_sum scope; with pack_bits it leaves
-        # the reduction already as b-bit wire words (core/wire.py) for the
-        # packed decode_apply_sum to consume.
-        with jax.named_scope("fed.encode_sum"):
-            part = (cohort.participation(cfg, valid, k_drop) if hetero
-                    else None)
-            if fused:
-                z_sum = mech.quantize_sum_batch(grads, k_enc, weights=part,
-                                                pack_bits=pack_bits)
-            else:
-                z = mech.quantize_batch(grads, k_enc)
-                if hetero:
-                    z = z * part.astype(z.dtype)[:, None]  # non-participants
-        if not fused:
-            with jax.named_scope("fed.secure_sum"):
-                z_sum = jnp.sum(z, axis=0, dtype=z.dtype)
-        with jax.named_scope("fed.decode_apply"):
-            if not hetero:
-                n_real = jnp.int32(cfg.clients_per_round)
-                n_dec = cfg.clients_per_round
-            else:
-                n_real = jnp.sum(part, dtype=jnp.int32)
-                # an empty round releases nothing and moves nothing
-                n_dec = jnp.maximum(n_real, 1)
-            if fused_apply:
-                new = decode_apply_sum(flat, z_sum, mech.params, n_dec,
-                                       cfg.lr, pack_bits=pack_bits)
-                new_state = opt_state
-                if hetero:
-                    new = jnp.where(n_real > 0, new, flat)
-            else:
-                g_hat = mech.decode_sum(z_sum, n_dec)
-                new, new_state = apply(flat, opt_state, g_hat, n_real)
-        new, new_state = jax.lax.optimization_barrier((new, new_state))
-        if pack_bits is not None and cfg.collect_sums:
-            # the collected observable stays the DENSE sum (exact unpack),
-            # so the cross-engine / packed-vs-unpacked equality suites
-            # compare like with like; without collect_sums the unpack is
-            # dead code and never compiles into the round.
-            z_sum = wire.unpack_bits(z_sum, pack_bits, flat.shape[0])
+        j = jax.lax.axis_index("shard") if multi else None
+        if streamed:
+            batch, valid = data, None
+            if hetero:  # re-derive the replicated validity mask
+                _, valid = cohort.sample_slate(cfg, slate, k_sample)
+        else:
+            ids, valid = cohort.sample_slate(cfg, slate, k_sample)
+            if multi:
+                ids = jax.lax.dynamic_slice_in_dim(ids, j * n_per, n_per)
+            batch = index_batch(data, ids)
+        grads = cohort_grads(client_grad, flat, batch)
+        z_sum, part = encode_sum(
+            mech, cfg, grads, k_enc, valid if hetero else None, k_drop,
+            pack_bits=pack_bits, shard=(j, slate) if multi else None,
+            exchange=exchange)
+        new, new_state, n_real = decode_apply(
+            mech, cfg, opt, flat, opt_state, z_sum, part,
+            fused=fused_apply, pack_bits=pack_bits)
+        new, new_state, z_sum = round_boundary(new, new_state, z_sum, cfg,
+                                               pack_bits)
         return new, new_state, key, z_sum, n_real
 
     return round_step
+
+
+def make_shard_round_step(mech, cfg, opt, slate, shards, client_grad):
+    """The shard engine's round step: ``make_round_step`` over ``shards``."""
+    return make_round_step(mech, cfg, opt, slate, client_grad,
+                           shards=shards, streamed=cfg.staging == "stream")
 
 
 def pick_unroll(cfg, length: int) -> int:
@@ -285,118 +354,3 @@ def make_block(round_step, cfg, *, streamed: bool = False):
         return flat, opt_state, key, sums, ns
 
     return block
-
-
-def make_shard_round_step(mech, cfg, opt, slate, shards, client_grad):
-    """The shard engine's round step (inside shard_map over ('shard',)).
-
-    Identical key evolution to the scan engine: the key is replicated, so
-    every shard derives the same k_sample/k_enc/k_drop and the same global
-    cohort slate + masks. Each shard grads+encodes its slate/shards cohort
-    slice (the row_offset keeps the RNG counters identical to the
-    unsharded batch), takes its partial integer sum, and ONE cross-shard
-    secure_sum of packed level indices crosses the shard boundary — never
-    floats — before the replicated decode + server-optimizer step.
-
-    On a 1-shard mesh the shard-local slice IS the whole cohort and the
-    RNG row offset IS zero: both are specialized away statically so the
-    round body traces to exactly the scan engine's program (the
-    bit-identity contract for free, and none of the dynamic-slice /
-    traced-offset overhead on single-device runs). Multi-shard meshes
-    take the generic path.
-    """
-    hetero = cohort.is_hetero(cfg)
-    apply = make_server_apply(opt, cfg, hetero)
-    fused = cfg.fused_rounds
-    fused_apply = use_fused_apply(mech, cfg)
-    n = cfg.clients_per_round
-    n_per = slate // shards
-    bound = mech.sum_bound(slate)  # forced-packing safety checked at init
-    prefer_packed = cfg.shard_packed is None or cfg.shard_packed
-    pack_bits = hot_path_pack_bits(mech, cfg, slate)
-    streamed = cfg.staging == "stream"
-    multi = shards > 1
-
-    def round_step(flat, opt_state, key, data):
-        key, k_sample, k_enc, k_drop = cohort.split_round_keys(cfg, key)
-        j = jax.lax.axis_index("shard") if multi else 0
-        valid = None
-        if streamed:
-            # the block staging already gathered this round's slate in
-            # sampled order and sharded it over the mesh; the device
-            # re-derives only the (replicated) validity mask from the
-            # same k_sample the host replayed.
-            batch = data
-            if hetero:
-                _, valid = cohort.sample_slate(cfg, slate, k_sample)
-        else:
-            ids, valid = cohort.sample_slate(cfg, slate, k_sample)
-            if multi:
-                ids = jax.lax.dynamic_slice_in_dim(ids, j * n_per, n_per)
-            batch = index_batch(data, ids)
-        grads = cohort_grads(client_grad, flat, batch)
-        with jax.named_scope("fed.encode_sum"):
-            local = None
-            if hetero:
-                # replicated full-slate participation; each shard masks its
-                # own row slice out of the partial sum
-                part = cohort.participation(cfg, valid, k_drop)
-                local = (jax.lax.dynamic_slice_in_dim(part, j * n_per, n_per)
-                         if multi else part)
-            if fused:
-                # one streamed clip->encode->shard-local-sum: the per-shard
-                # (n_per, dim) encoded slice is never materialized, and the
-                # reduction the SecAgg boundary receives is already done —
-                # with pack_bits, already as b-bit wire words.
-                z_part = mech.quantize_sum_batch(
-                    grads, k_enc, weights=local,
-                    row_offset=j * n_per if multi else None,
-                    total_rows=slate if multi else None,
-                    pack_bits=pack_bits,
-                )
-            else:
-                z = mech.quantize_batch(
-                    grads, k_enc,
-                    row_offset=j * n_per if multi else None,
-                    total_rows=slate if multi else None,
-                )
-                if hetero:
-                    z = z * local.astype(z.dtype)[:, None]
-        # The SecAgg boundary. Packed hot path: the shard-local partials
-        # are ALREADY minimal-width wire words, and int32 addition sums
-        # their fields independently (field-safety checked against the
-        # full-slate bound in hot_path_pack_bits), so one plain psum of
-        # words IS the exact cross-shard SecAgg sum — at b bits per
-        # coordinate on the interconnect. Dense path: integer level
-        # indices, minimal-width-packed by secure_sum_bounded when the
-        # bound allows (exact either way; the float 'none' baseline has
-        # bound 0 and takes the plain psum).
-        with jax.named_scope("fed.secure_sum"):
-            if not fused:
-                z_part = jnp.sum(z, axis=0, dtype=z.dtype)  # shard-local
-            if pack_bits is not None:
-                z_sum = jax.lax.psum(z_part, "shard")
-            else:
-                z_sum = secagg.secure_sum_bounded(
-                    z_part, ("shard",), bound, packed=prefer_packed
-                )
-        with jax.named_scope("fed.decode_apply"):
-            n_real = jnp.sum(part, dtype=jnp.int32) if hetero else jnp.int32(n)
-            n_dec = jnp.maximum(n_real, 1) if hetero else n
-            if fused_apply:
-                new = decode_apply_sum(flat, z_sum, mech.params, n_dec,
-                                       cfg.lr, pack_bits=pack_bits)
-                new_state = opt_state
-                if hetero:
-                    new = jnp.where(n_real > 0, new, flat)
-            else:
-                g_hat = mech.decode_sum(z_sum, n_dec)
-                new, new_state = apply(flat, opt_state, g_hat, n_real)
-        new, new_state = jax.lax.optimization_barrier((new, new_state))
-        if pack_bits is not None and cfg.collect_sums:
-            # collected observable = the DENSE sum (exact unpack); dead
-            # code unless collect_sums (see make_round_step)
-            z_sum = wire.unpack_bits(z_sum, pack_bits, flat.shape[0])
-        return new, new_state, key, z_sum, n_real
-
-    return round_step

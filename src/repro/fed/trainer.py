@@ -49,7 +49,6 @@ class FedTrainer:
         self.mech = mech
         self.cfg = fed_cfg
         self._mesh = None
-        self._plan = None
         self._task_ctx = None  # set by the shard engine on a 2-D mesh
         self.shards = 1
         # Heterogeneous cohorts (docs/privacy.md): Poisson subsampling and/or
@@ -346,9 +345,7 @@ class FedTrainer:
     def evaluate(self):
         """Held-out metrics from the task; always reports "loss"."""
         flat = self.flat
-        if self._mesh is not None and (
-            self._plan is None or self._plan.model_axis is None
-        ):
+        if self._mesh is not None and self._task_ctx is None:
             # the shard engine leaves flat committed (replicated) on the
             # mesh; evaluate on an uncommitted host copy so the eval jit
             # never mixes device sets with the single-device eval arrays.

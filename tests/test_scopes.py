@@ -3,7 +3,8 @@
 * the jitted round carries the device scopes ``fed.grads``,
   ``fed.client_grad``, ``fed.encode_sum``, ``fed.secure_sum`` and
   ``fed.decode_apply`` in its ops' metadata, on every path the engines
-  build (the shard engine on four devices: tests/shard_engine_checks.py);
+  build — the async engine's buffered round included (the shard engine
+  on four devices: tests/shard_engine_checks.py);
 * ``Timings.scope`` writes a ``fed.<name>`` span into a profiler trace's
   host plane, on the device ops' clock;
 * the engines' host scopes (``dispatch``, ``finish_block``, ``account``)
@@ -14,6 +15,7 @@ import os
 import re
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from conftest import small_trainer
@@ -32,6 +34,12 @@ def _lower_block(tr):
     args = (tr.flat, tr.opt_state, tr._key, tr.client_data)
     if tr.cfg.engine == "perround":
         return tr.engine._round_jit.lower(*args)
+    if tr.cfg.engine == "async":
+        # the buffered round: parameter ring, per-row staleness, delivered
+        stale = jnp.zeros(tr.slate, jnp.int32)
+        delivered = jnp.ones(tr.slate, bool)
+        return tr.engine._round_jit.lower(tr.engine._hist, *args[1:], stale,
+                                          delivered)
     if tr.cfg.engine == "shard":
         return tr.engine._block_jit(2).lower(*args)
     return tr.engine._block_jit.lower(*args, 2)
@@ -42,7 +50,8 @@ def _lower_block(tr):
     ("perround", {}),
     ("shard", {"shards": 1}),
     ("scan", {"subsampling": "poisson", "dropout": 0.3}),
-], ids=["scan", "perround", "shard-1", "scan-poisson-dropout"])
+    ("async", {"async_max_staleness": 1}),
+], ids=["scan", "perround", "shard-1", "scan-poisson-dropout", "async-stale"])
 def test_round_carries_the_five_scopes(engine, overrides):
     tr = small_trainer(engine, **overrides)
     assert _scopes(_lower_block(tr)) == FIVE
